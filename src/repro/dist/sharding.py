@@ -26,7 +26,8 @@ import threading
 from typing import Any, Mapping, Sequence
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+import numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # --------------------------------------------------------------------------
 # logical axis names
@@ -112,6 +113,21 @@ def _mesh_axes_for(rules: Mapping[str, Sequence[str]], name) -> tuple[str, ...]:
 # --------------------------------------------------------------------------
 # mesh construction
 # --------------------------------------------------------------------------
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
+    """The repo's one mesh constructor: every axis ``Auto``.
+
+    ``jax.make_mesh`` makes ``Explicit`` axes, which
+    ``with_sharding_constraint`` (``constrain``) rejects.  ``devices``
+    pins the exact device order (a survivor mesh); without it JAX lays
+    the mesh over all devices.
+    """
+    types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=types)
+    devs = np.asarray(devices, dtype=object).reshape(tuple(shape))
+    return Mesh(devs, tuple(axes), axis_types=types)
+
+
 def divisor_mesh(num_items: int, axis: str):
     """1-D mesh over ``axis`` sized to the largest divisor of
     ``num_items`` that fits the available devices.
@@ -128,7 +144,7 @@ def divisor_mesh(num_items: int, axis: str):
         if num_items % d == 0:
             m = d
             break
-    return jax.make_mesh((m,), (axis,))
+    return make_mesh((m,), (axis,))
 
 
 # --------------------------------------------------------------------------

@@ -12,6 +12,16 @@ import dataclasses
 import numpy as np
 
 
+def stable_argsort(keys: np.ndarray) -> np.ndarray:
+    """``np.argsort(keys, kind="stable")`` for non-negative int32 keys.
+
+    One value sort of ``key << 32 | index`` — several times faster than a
+    stable argsort at Graph500 edge counts (10^7–10^8).
+    """
+    idx = np.arange(keys.size, dtype=np.int64)
+    return np.sort((np.asarray(keys).astype(np.int64) << 32) | idx) & 0xFFFFFFFF
+
+
 @dataclasses.dataclass(frozen=True)
 class Graph:
     """An immutable directed graph in COO form.
@@ -51,7 +61,7 @@ class Graph:
         This is the layout agents use to build edge blocks: "an agent selects
         a vertex and retrieves its outer edges" (paper Sec. II-B).
         """
-        order = np.argsort(self.src, kind="stable")
+        order = stable_argsort(self.src)
         return Graph(
             num_vertices=self.num_vertices,
             src=self.src[order],
@@ -70,7 +80,7 @@ class Graph:
 
     def csr(self) -> tuple[np.ndarray, np.ndarray]:
         """(indptr, edge_order) grouping edges by src; weights/dst follow order."""
-        order = np.argsort(self.src, kind="stable")
+        order = stable_argsort(self.src)
         counts = np.bincount(self.src, minlength=self.num_vertices)
         indptr = np.zeros(self.num_vertices + 1, dtype=np.int64)
         np.cumsum(counts, out=indptr[1:])

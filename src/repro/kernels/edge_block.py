@@ -1,20 +1,28 @@
-"""Pallas TPU kernel: the GX-Plug daemon block program.
+"""Pallas TPU kernel: the GX-Plug daemon tile program.
 
-One grid step processes one edge block with its paired vertex block resident
-in VMEM (paper Sec. II-B: "each edge block is associated with a paired
-vertex block"). TPU adaptation (DESIGN.md §2):
+One grid step processes one edge tile with its paired compact vertex
+blocks resident in VMEM (paper Sec. II-B: "each edge block is associated
+with a paired vertex block").  TPU adaptation (DESIGN.md §2):
 
-* gathers through block-local indices become **one-hot matmuls** on the MXU
-  (src_onehot @ vertex_block), not HBM random access;
+* gathers through tile-local indices become **one-hot matmuls** on the
+  MXU (``gather="onehot"``), not HBM random access, bit-exact through a
+  three-piece bf16 split (``_onehot_dot``); ``gather="take"`` instead
+  gathers per edge in XLA ahead of the kernel (Mosaic's in-kernel
+  dynamic gather spans one vreg only) and the kernel fuses Gen + Merge;
 * the per-destination MSGMerge becomes a dense masked reduction:
-  sum-monoid → one-hot-transpose matmul (MXU); min/max → masked VPU
-  reduction per state column;
-* the Pallas grid pipeline overlaps the HBM→VMEM DMA of block *i+1* with
-  compute on block *i* — the hardware form of the paper's pipeline shuffle.
+  sum-monoid → one-hot matmul (MXU); min/max/or → masked VPU reduction
+  per state column;
+* the Pallas grid pipeline overlaps the HBM→VMEM DMA of tile *i+1* with
+  compute on tile *i* — the hardware form of the paper's pipeline shuffle.
 
-VMEM budget per grid step (f32): VB·K + VB·A + 3·B + B·VB (one-hot) +
-B·K — with the default B=512, VB=512, K≤8 this is ≲1.5 MiB, comfortably
-inside the ~16 MiB VMEM of a TPU core, leaving room for double buffering.
+Layout: every operand is K-major — vertex blocks ``(T, K, S)`` and
+per-edge vectors ``(T, 1, E)`` — so the long axis sits on the 128 lanes.
+A ``(T, S, K)`` or ``(T, E, 1)`` operand with small K would be padded to
+128 lanes in HBM (up to 128x its size).
+
+VMEM per grid step (f32, ET = RT = ST = 512, K ≤ 8): the (RT, ET) row
+one-hot and one (ET, RT) masked column at a time ≈ 2 MiB, plus the
+double-buffered blocks — well inside v5e's 16 MiB default scoped VMEM.
 """
 from __future__ import annotations
 
@@ -22,217 +30,136 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
 from repro.core.template import VertexProgram
 
+_MERGE_MONOIDS = ("max", "min", "or", "sum")
+_TOP16 = -(1 << 16)  # 0xFFFF0000: sign, exponent, top 7 mantissa bits
 
-def _kernel(vstate_ref, vaux_ref, lsrc_ref, ldst_ref, w_ref, emask_ref,
-            partial_ref, counts_ref, *, program: VertexProgram):
+
+def _onehot_dot(onehot, table):
+    """``onehot (M, S) @ table (S, K)`` on the MXU in three bf16 passes.
+
+    Each bf16 piece is the top 8 significant bits of what is left of the
+    f32 value, so the three pieces carry all 24 and add up to it exactly.
+    A gather (one 1 per one-hot row) is therefore bit-exact, and a
+    scatter-add (many 1s per row) is an f32 sum.  A HIGHEST-precision
+    f32 dot would round the largest finite f32 (the ``INF`` of the
+    shortest-path programs) up to inf, and 0·inf poisons the other rows.
+    The one-hot is the streamed operand and the narrow table the
+    stationary one, which keeps the MXU's weight loads to S/128.
+    """
+    oh = onehot.astype(jnp.bfloat16)
+    out = None
+    rest = table
+    for _ in range(3):
+        bits = lax.bitcast_convert_type(rest, jnp.int32) & _TOP16
+        piece = lax.bitcast_convert_type(bits, jnp.float32)
+        rest = rest - piece
+        part = jnp.dot(oh, piece.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+        out = part if out is None else out + part
+    return out
+
+
+def _tile_kernel(src_ref, aux_ref, row_ref, lsrc_ref, seg_ref, w_ref,
+                 emask_ref, partial_ref, counts_ref, *,
+                 program: VertexProgram, gather: str):
+    """One grid step = one edge tile: gather, Gen per edge, Merge per row.
+
+    ``seg`` is the tile-local row of every edge.  Where it is sorted and
+    every low-degree row lives inside one tile (degree bucketing), the
+    per-row merge here is final for those rows; split hub rows are
+    finished by the cross-tile segmented combine in ops.csr_aggregate.
+    """
     monoid = program.monoid
-    k = program.state_width
-    vstate = vstate_ref[0].astype(jnp.float32)  # (VB, K)
-    vaux = vaux_ref[0].astype(jnp.float32)  # (VB, A)
-    lsrc = lsrc_ref[0]  # (B,)
-    ldst = ldst_ref[0]
-    w = w_ref[0].astype(jnp.float32)  # (B, 1)
-    emask = emask_ref[0].astype(jnp.float32)  # (B,)
-
-    b = lsrc.shape[0]
-    vb = vstate.shape[0]
-    col = jax.lax.broadcasted_iota(jnp.int32, (b, vb), 1)
-    src_oh = (lsrc[:, None] == col).astype(jnp.float32)  # (B, VB)
-    dst_oh = (ldst[:, None] == col).astype(jnp.float32)
-
-    # Gather via MXU: (B, VB) @ (VB, K)
-    s = src_oh @ vstate
-    d = dst_oh @ vstate
-    sa = src_oh @ vaux
-
-    msgs = program.msg_gen(s, d, w, sa)  # (B, K)
-
-    if monoid.name == "sum":
-        masked = msgs * emask[:, None]
-        partial = dst_oh.T @ masked  # (VB, K) scatter-add on MXU
-    elif monoid.name in ("min", "max", "or"):
-        # masked reduction per column: (VB, B) select matrix ("or" over
-        # {0,1} indicators is exactly max — see core.template.OR)
-        sel = (dst_oh.T > 0.0) & (emask[None, :] > 0.0)  # (VB, B)
-        cols = []
-        for i in range(k):  # K is small & static
-            mat = jnp.where(sel, msgs[:, i][None, :], monoid.identity)
-            red = (jnp.min(mat, axis=1) if monoid.name == "min"
-                   else jnp.max(mat, axis=1))
-            cols.append(red)
-        partial = jnp.stack(cols, axis=1)
-    else:
+    if monoid.name not in _MERGE_MONOIDS:
         # trace-time check, same contract as Monoid.segment_reduce /
         # scatter_at: an unknown monoid must raise, never silently
         # merge with the wrong operator
         raise ValueError(
             f"monoid {monoid.name!r} has no Pallas merge rule; known: "
-            "['max', 'min', 'or', 'sum']")
-    counts = (dst_oh.T @ emask[:, None])[:, 0]  # (VB,)
-
-    partial_ref[0] = partial.astype(partial_ref.dtype)
-    counts_ref[0] = counts.astype(jnp.int32)
-
-
-def edge_block_pallas(vstate, vaux, lsrc, ldst, w, emask_f32, *,
-                      program: VertexProgram, interpret: bool = True):
-    """Runs the daemon program over all blocks.
-
-    Args (pre-gathered by the agent — see ops.edge_block_aggregate):
-      vstate (nb, VB, K) f32, vaux (nb, VB, A) f32,
-      lsrc/ldst (nb, B) i32, w (nb, B, 1) f32, emask_f32 (nb, B) f32.
-    Returns: partial (nb, VB, K) f32, counts (nb, VB) i32.
-    """
-    nb, vb, k = vstate.shape
-    a = vaux.shape[2]
-    b = lsrc.shape[1]
-    kern = functools.partial(_kernel, program=program)
-    out_shape = [
-        jax.ShapeDtypeStruct((nb, vb, k), jnp.float32),
-        jax.ShapeDtypeStruct((nb, vb), jnp.int32),
-    ]
-    grid = (nb,)
-    in_specs = [
-        pl.BlockSpec((1, vb, k), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, vb, a), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, b), lambda i: (i, 0)),
-        pl.BlockSpec((1, b), lambda i: (i, 0)),
-        pl.BlockSpec((1, b, 1), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, b), lambda i: (i, 0)),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, vb, k), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, vb), lambda i: (i, 0)),
-    ]
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(vstate, vaux, lsrc, ldst, w, emask_f32)
-
-
-# --------------------------------------------------------------------------
-# CSR tile kernel: the fused gather + Gen + segmented-Merge daemon program
-# over the dst-grouped tile layout of graph/compaction.py (DESIGN.md §3.1)
-# --------------------------------------------------------------------------
-def _csr_tile_kernel(vsrc_ref, vaux_ref, rowst_ref, lsrc_ref, seg_ref,
-                     w_ref, emask_ref, partial_ref, counts_ref, *,
-                     program: VertexProgram, gather: str):
-    """One grid step = one edge tile: gather the tile's compact src/row
-    blocks from VMEM, Gen per edge, Merge per row.
-
-    Because ``seg`` is a *sorted* tile-local row index and every
-    low-degree row lives entirely inside one tile (degree bucketing),
-    the per-row merge here is final for those rows; split hub rows are
-    finished by the cross-tile segmented combine in ops.csr_aggregate.
-    The merge itself is the MXU/VPU form: one-hot-transpose matmul for
-    sum, a masked per-column reduction for the selection monoids
-    (min/max/or) — identical math to the reference XLA twin.
-    """
-    monoid = program.monoid
+            f"{list(_MERGE_MONOIDS)}")
     k = program.state_width
-    vsrc = vsrc_ref[0].astype(jnp.float32)    # (ST, K)
-    vaux = vaux_ref[0].astype(jnp.float32)    # (ST, A)
-    rowst = rowst_ref[0].astype(jnp.float32)  # (RT, K)
-    lsrc = lsrc_ref[0]                        # (ET,)
-    seg = seg_ref[0]                          # (ET,)
-    w = w_ref[0].astype(jnp.float32)          # (ET, 1)
-    emask = emask_ref[0].astype(jnp.float32)  # (ET,)
-
-    et = lsrc.shape[0]
-    st = vsrc.shape[0]
-    rt = rowst.shape[0]
-    rcol = jax.lax.broadcasted_iota(jnp.int32, (et, rt), 1)
-    row_oh = (seg[:, None] == rcol).astype(jnp.float32)  # (ET, RT)
+    seg = seg_ref[...]                      # (1, ET) i32
+    emask_f = emask_ref[...]                # (1, ET) f32
+    et = seg.shape[1]
+    rt = partial_ref.shape[1]
+    seg_col = seg.T                         # (ET, 1)
+    row_oh = seg_col == lax.broadcasted_iota(jnp.int32, (et, rt), 1)
     if gather == "onehot":
-        scol = jax.lax.broadcasted_iota(jnp.int32, (et, st), 1)
-        src_oh = (lsrc[:, None] == scol).astype(jnp.float32)
-        s = src_oh @ vsrc   # MXU gathers
-        sa = src_oh @ vaux
-        d = row_oh @ rowst
-    else:  # "take": vector gathers from the VMEM-resident blocks
-        s = vsrc[lsrc]
-        sa = vaux[lsrc]
-        d = rowst[seg]
+        st = src_ref.shape[1]
+        src_oh = lsrc_ref[...].T == lax.broadcasted_iota(
+            jnp.int32, (et, st), 1)                         # (ET, ST)
+        s = _onehot_dot(src_oh, src_ref[...].T)             # (ET, K)
+        sa = _onehot_dot(src_oh, aux_ref[...].T)            # (ET, A)
+        d = _onehot_dot(row_oh, row_ref[...].T)             # (ET, K)
+    else:  # "take": gathered per edge ahead of the kernel
+        s, sa, d = src_ref[...].T, aux_ref[...].T, row_ref[...].T
 
-    msgs = program.msg_gen(s, d, w, sa)  # (ET, K)
+    msgs = program.msg_gen(s, d, w_ref[...].T, sa)          # (ET, K)
 
+    sel = row_oh & (emask_f.T > 0.0)                        # (ET, RT)
     if monoid.name == "sum":
-        masked = msgs * emask[:, None]
-        partial = row_oh.T @ masked  # (RT, K) scatter-add on MXU
-    elif monoid.name in ("min", "max", "or"):
-        sel = (row_oh.T > 0.0) & (emask[None, :] > 0.0)  # (RT, ET)
-        cols = []
-        for i in range(k):
-            mat = jnp.where(sel, msgs[:, i][None, :], monoid.identity)
-            red = (jnp.min(mat, axis=1) if monoid.name == "min"
-                   else jnp.max(mat, axis=1))
-            cols.append(red)
-        partial = jnp.stack(cols, axis=1)
+        live_t = ((seg == lax.broadcasted_iota(jnp.int32, (rt, et), 0))
+                  & (emask_f > 0.0))                        # (RT, ET)
+        # dead slots may hold any message; zero them so no 0·inf reaches
+        # the MXU accumulator
+        msgs = jnp.where(emask_f.T > 0.0, msgs, 0.0)
+        partial = _onehot_dot(live_t, msgs).T               # (K, RT)
     else:
-        raise ValueError(
-            f"monoid {monoid.name!r} has no Pallas merge rule; known: "
-            "['max', 'min', 'or', 'sum']")
-    counts = (row_oh.T @ emask[:, None])[:, 0]  # (RT,)
+        # masked reduction per column over the (ET, RT) select matrix
+        # ("or" over {0,1} indicators is exactly max — core.template.OR)
+        red = jnp.min if monoid.name == "min" else jnp.max
+        partial = jnp.concatenate(
+            [red(jnp.where(sel, msgs[:, i:i + 1], monoid.identity),
+                 axis=0, keepdims=True) for i in range(k)], axis=0)
+    counts = jnp.sum(sel.astype(jnp.float32), axis=0, keepdims=True)
 
-    partial_ref[0] = partial.astype(partial_ref.dtype)
-    counts_ref[0] = counts.astype(jnp.int32)
+    partial_ref[...] = partial.astype(partial_ref.dtype)
+    counts_ref[...] = counts.astype(jnp.int32)
 
 
-def csr_tile_pallas(vsrc, vaux, rowst, lsrc, seg, w, emask_f32, *,
-                    program: VertexProgram, gather: str = "take",
-                    interpret: bool = True):
-    """Runs the fused CSR tile program over all tiles.
+def _tile_spec(shape):
+    """Block of one tile: the leading tile axis squeezed, the rest whole."""
+    nd = len(shape)
+    return pl.BlockSpec((None,) + tuple(shape[1:]),
+                        lambda i: (i,) + (0,) * (nd - 1))
 
-    Args (pre-gathered compact blocks — see ops.csr_aggregate):
-      vsrc (T, ST, K) f32, vaux (T, ST, A) f32 — per-tile src blocks;
-      rowst (T, RT, K) f32 — per-tile row (dst) state blocks;
-      lsrc/seg (T, ET) i32, w (T, ET, 1) f32, emask_f32 (T, ET) f32.
-    Returns: partial (T, RT, K) f32, counts (T, RT) i32 — per-tile row
-    partials; split hub rows still need the cross-tile combine.
 
-    VMEM per grid step (f32): ST·(K+A) + RT·K + 3·ET + ET·RT (row
-    one-hot) + ET·K — with ET=512, RT≤512, K≤8 this is ≲1.2 MiB, well
-    inside a TPU core's ~16 MiB with double buffering to spare.
+def csr_tile_pallas(src, aux, row, lsrc, seg, w, emask_f32, *,
+                    row_tile: int, program: VertexProgram, gather: str,
+                    interpret: bool):
+    """Runs the fused tile program over all T tiles (one per grid step).
+
+    Args (K-major, see the module docstring):
+      gather="onehot": src (T, K, ST), aux (T, A, ST) — per-tile src
+        blocks; row (T, K, RT) — per-tile row (dst) state blocks.
+      gather="take": src (T, K, ET), aux (T, A, ET), row (T, K, ET) —
+        the same values already gathered per edge.
+      lsrc/seg (T, 1, ET) i32, w (T, 1, ET) f32, emask_f32 (T, 1, ET) f32.
+      row_tile: RT, the row-block width of the outputs.
+    Returns: partial (T, K, RT) f32, counts (T, 1, RT) i32 — per-tile row
+    partials, the monoid identity / zero at rows no live edge reaches;
+    split hub rows still need the cross-tile combine.
     """
-    t, st, k = vsrc.shape
-    a = vaux.shape[2]
-    rt = rowst.shape[1]
-    et = lsrc.shape[1]
-    kern = functools.partial(_csr_tile_kernel, program=program,
-                             gather=gather)
-    out_shape = [
-        jax.ShapeDtypeStruct((t, rt, k), jnp.float32),
-        jax.ShapeDtypeStruct((t, rt), jnp.int32),
-    ]
-    in_specs = [
-        pl.BlockSpec((1, st, k), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, st, a), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, rt, k), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, et), lambda i: (i, 0)),
-        pl.BlockSpec((1, et), lambda i: (i, 0)),
-        pl.BlockSpec((1, et, 1), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, et), lambda i: (i, 0)),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, rt, k), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, rt), lambda i: (i, 0)),
-    ]
+    if gather not in ("take", "onehot"):
+        raise ValueError(f"gather must be 'take' or 'onehot', got {gather!r}")
+    t, k, _ = src.shape
+    kern = functools.partial(_tile_kernel, program=program, gather=gather)
+    out_shape = [jax.ShapeDtypeStruct((t, k, row_tile), jnp.float32),
+                 jax.ShapeDtypeStruct((t, 1, row_tile), jnp.int32)]
+    args = (src, aux, row, lsrc, seg, w, emask_f32)
     return pl.pallas_call(
         kern,
         grid=(t,),
-        in_specs=in_specs,
-        out_specs=out_specs,
+        in_specs=[_tile_spec(a.shape) for a in args],
+        out_specs=[_tile_spec(o.shape) for o in out_shape],
         out_shape=out_shape,
         interpret=interpret,
-    )(vsrc, vaux, rowst, lsrc, seg, w, emask_f32)
+    )(*args)
 
 
 # --------------------------------------------------------------------------

@@ -69,7 +69,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            scale: float | None = None,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret: bool):
     """q (B, Hq, S, D); k, v (B, Hkv, S, D); returns (B, Hq, S, D)."""
     b, hq, s, d = q.shape
     hkv = k.shape[1]

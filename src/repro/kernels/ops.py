@@ -1,10 +1,19 @@
 """jit'd public wrappers for the Pallas kernels.
 
-Backend policy: on CPU (this container) Pallas runs in ``interpret=True``
-mode for correctness validation; models/benchmarks can also select the
-pure-jnp reference implementations (``impl="reference"``), which is what
-the 512-device dry-run lowers (see DESIGN.md §8 — kernels are validated at
-small scale in interpret mode; roofline terms come from the XLA path).
+Backend policy, decided in one place (``_default_interpret``): on a TPU
+every Pallas kernel compiles through Mosaic; on any other backend it runs
+in ``interpret=True`` mode, which checks correctness only.  Models and
+benchmarks can also select the pure-jnp reference implementations
+(``impl="reference"``), which is what the 512-device dry-run lowers.
+
+On one TPU v5e chip the graph daemon's CSR tile kernel
+(``csr_tile_pallas`` with ``gather="take"``, one-hot merge, 512-edge
+tiles) has run inside the sharded fused drive loop at Graph500 SCALE 22,
+and on a four-chip v5e mesh at SCALE 18 (``chip_smoke.py``).
+``gather="onehot"`` and ``edge_block_aggregate``
+have only been compiled for a described v5e
+(``tests/test_chip_compile.py``); the flash-attention and SSD kernels
+have only run in interpret mode.
 """
 from __future__ import annotations
 
@@ -15,13 +24,19 @@ import jax.numpy as jnp
 
 from repro.core.template import VertexProgram
 from repro.kernels import ref
-from repro.kernels.edge_block import csr_tile_pallas, edge_block_pallas
+from repro.kernels.edge_block import csr_tile_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.ssd_scan import ssd_chunk_pallas
 
 
 def _default_interpret() -> bool:
+    """Interpret Pallas kernels everywhere but on a TPU, never on one."""
     return jax.default_backend() != "tpu"
+
+
+def _kmajor(table, ids):
+    """``table[ids]`` as K-major blocks: (T, S) ids → (T, K, S)."""
+    return jnp.swapaxes(table[ids], 1, 2)
 
 
 # --------------------------------------------------------------------------
@@ -30,18 +45,25 @@ def _default_interpret() -> bool:
 def edge_block_aggregate(state, aux, vids, lsrc, ldst, w, emask, *,
                          program: VertexProgram, impl: str = "pallas"):
     """Agent-side wrapper: gathers the paired vertex blocks, then runs the
-    daemon program (Pallas) over the block grid."""
+    daemon program over the block grid — the tile kernel with the vertex
+    block as both its src and its row block, merging by ``ldst``."""
     if impl == "reference":
         return ref.edge_block_aggregate(state, aux, vids, lsrc, ldst, w,
                                         emask, program=program)
     if aux.shape[1] == 0:  # zero-width aux: Pallas BlockSpecs need dims >= 1
         aux = jnp.zeros((state.shape[0], 1), state.dtype)
-    vstate = state[vids]  # (nb, VB, K) — agent "download" into block layout
-    vaux = aux[vids]
-    emf = emask.astype(jnp.float32)
-    return edge_block_pallas(vstate, vaux, lsrc, ldst, w.astype(jnp.float32),
-                             emf, program=program,
-                             interpret=_default_interpret())
+    nb, b = lsrc.shape
+    vstate = _kmajor(state, vids)  # agent "download" into block layout
+
+    def edge_rows(x):
+        return x.reshape(nb, 1, b)
+
+    partial, counts = csr_tile_pallas(
+        vstate, _kmajor(aux, vids), vstate, edge_rows(lsrc),
+        edge_rows(ldst), edge_rows(w.astype(jnp.float32)),
+        edge_rows(emask.astype(jnp.float32)), row_tile=vids.shape[1],
+        program=program, gather="onehot", interpret=_default_interpret())
+    return jnp.swapaxes(partial, 1, 2), counts.reshape(nb, -1)
 
 
 # --------------------------------------------------------------------------
@@ -110,14 +132,14 @@ def _csr_tiles_xla(vsrc, vaux, rowst, lsrc, seg, w, emask, *,
 
 
 def csr_aggregate(state, aux, csr: dict, *, program: VertexProgram,
-                  num_vertices: int, config, interpret: bool | None = None):
+                  num_vertices: int, config):
     """Fused gather + Gen + segmented Merge over CSR tiles → (N, K) agg.
 
     Args:
       state (N, K) f32, aux (N, A) f32 — the shard vertex table.
       csr: dict of per-tile arrays with leading tile axis T (the
         ``CSRTileSet.arrays()`` layout): rows (T, RT), seg/lsrc/gsrc/gdst
-        (T, ET), svids (T, ST), w (T, ET, 1), emask (T, ET) bool.
+        (T, ET), svids (T, ST), w (T, ET), emask (T, ET) bool.
         ``emask`` may already carry per-edge frontier filtering.
       config: a ``kernels.autotune.CSRConfig`` (or any object with
         edge_tile/lowering/merge/gather attributes).  ``merge="flat"``
@@ -150,20 +172,32 @@ def csr_aggregate(state, aux, csr: dict, *, program: VertexProgram,
         agg = monoid.segment_reduce(msgs, gdst, n)
         cnt = jax.ops.segment_sum(emf.astype(jnp.int32), gdst, n)
     else:
-        vsrc = state[csr["svids"]]   # (T, ST, K) compact src blocks
-        vaux = aux[csr["svids"]]
-        rowst = state[csr["rows"]]   # (T, RT, K) compact row blocks
         if config.lowering == "pallas":
+            t, et = csr["lsrc"].shape
+
+            def edge_rows(x):
+                return x.reshape(t, 1, et)
+
+            # "take" gathers per edge from the global ids (same values as
+            # svids[lsrc] / rows[seg] on live slots); "onehot" hands the
+            # kernel the compact blocks
+            src_ids, row_ids = ((csr["gsrc"], csr["gdst"])
+                                if config.gather == "take"
+                                else (csr["svids"], csr["rows"]))
             partial, counts = csr_tile_pallas(
-                vsrc, vaux, rowst, csr["lsrc"], csr["seg"], w,
-                emask.astype(jnp.float32), program=program,
-                gather=config.gather,
-                interpret=(_default_interpret() if interpret is None
-                           else interpret))
+                _kmajor(state, src_ids), _kmajor(aux, src_ids),
+                _kmajor(state, row_ids), edge_rows(csr["lsrc"]),
+                edge_rows(csr["seg"]), edge_rows(w),
+                edge_rows(emask.astype(jnp.float32)),
+                row_tile=csr["rows"].shape[1], program=program,
+                gather=config.gather, interpret=_default_interpret())
+            partial = jnp.swapaxes(partial, 1, 2)
+            counts = counts.reshape(t, -1)
         else:
             partial, counts = _csr_tiles_xla(
-                vsrc, vaux, rowst, csr["lsrc"], csr["seg"], w, emask,
-                program=program, merge=config.merge, gather=config.gather)
+                state[csr["svids"]], aux[csr["svids"]], state[csr["rows"]],
+                csr["lsrc"], csr["seg"], w, emask, program=program,
+                merge=config.merge, gather=config.gather)
         # cross-tile combine: finishes split hub rows and folds every
         # tile's row partials into the shard aggregate
         rows = csr["rows"].reshape(-1)

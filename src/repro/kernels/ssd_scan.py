@@ -58,7 +58,7 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
     gate_ref[0, 0] = jnp.exp(cum).astype(gate_ref.dtype)
 
 
-def ssd_chunk_pallas(x, dt, a, b_mat, c_mat, *, interpret: bool = True):
+def ssd_chunk_pallas(x, dt, a, b_mat, c_mat, *, interpret: bool):
     """Within-chunk SSD over all (batch, chunk, head) cells.
 
     Shapes (heads already expanded to H):

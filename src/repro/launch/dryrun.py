@@ -1,9 +1,10 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512").strip()
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
-The two lines above MUST precede every other import — jax locks the device
+The lines above MUST precede every other import — jax locks the device
 count at first initialization. 512 host devices back both the single-pod
 16×16 mesh (first 256) and the multi-pod 2×16×16 mesh.
 
@@ -34,6 +35,7 @@ import jax.numpy as jnp
 from repro.configs import ARCH_NAMES, SHAPES, get_config, shape_cells
 from repro.dist import sharding as shd
 from repro.launch import hlo_analysis
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import (batch_specs, choose_microbatches, decode_specs,
                                 params_specs)
@@ -247,6 +249,7 @@ def roofline_terms(record: dict) -> dict:
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES)
     ap.add_argument("--shape", choices=tuple(SHAPES))

@@ -28,11 +28,13 @@ if "xla_force_host_platform_device_count" not in os.environ.get(
 
 from repro.dist.fault import FailureSchedule, FleetMonitor  # noqa: E402
 from repro.graph import generate  # noqa: E402
+from repro.launch.cache import use_compile_cache  # noqa: E402
 from repro.serve import (GraphServeRouter, GraphServeSession,  # noqa: E402
                          generate_workload, replay)
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--num-vertices", type=int, default=2_000)
     ap.add_argument("--num-edges", type=int, default=16_000)

@@ -13,12 +13,14 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_NAMES, get_config, get_reduced
 from repro.dist import sharding as shd
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import Model
 from repro.train.serve import make_decode_step, make_prefill_step
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES, default="stablelm-1.6b")
     ap.add_argument("--reduced", action="store_true")

@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config, get_reduced
 from repro.dist import fault, sharding as shd
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import Model
 from repro.train import checkpoint as ckpt
@@ -61,9 +62,8 @@ def remesh_live_state(params, opt_state, axes, opt_axes, survivors):
             model_parallel = sh.mesh.shape["model"]
             break
     plan = fault.elastic_plan(len(survivors), model_parallel=model_parallel)
-    devs = np.asarray(survivors[:plan.size],
-                      dtype=object).reshape(plan.shape)
-    mesh = jax.sharding.Mesh(devs, plan.axis_names)
+    mesh = shd.make_mesh(plan.shape, plan.axis_names,
+                         devices=survivors[:plan.size])
     rules = shd.make_rules(mesh)
     params = jax.device_put(params,
                             shd.tree_shardings(params, axes, mesh, rules))
@@ -73,6 +73,7 @@ def remesh_live_state(params, opt_state, axes, opt_axes, survivors):
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES, default="stablelm-1.6b")
     ap.add_argument("--reduced", action="store_true",

@@ -716,7 +716,7 @@ class ShardedDaemon(VectorizedDaemon):
                 "seg": seg.reshape(s_l * nt, et),
                 "lsrc": lsrc.reshape(s_l * nt, et),
                 "svids": svids.reshape(s_l * nt, -1),
-                "w": w.reshape(s_l * nt, et, 1),
+                "w": w.reshape(s_l * nt, et),
                 "emask": em.reshape(s_l * nt, et),
                 "gsrc": gsrc.reshape(s_l * nt, et),
                 "gdst": gdst.reshape(s_l * nt, et),

@@ -61,6 +61,7 @@ from repro.core.pow2 import next_pow2
 from repro.core.sync import LRUVertexCache, SyncStats, can_skip_sync
 from repro.core.template import VertexProgram
 from repro.dist import fault as dist_fault
+from repro.dist import sharding as shd
 from repro.graph import mutation as graph_mutation
 from repro.graph.structure import EdgePartition, Graph
 from repro.plug.computation import BSP, GAS, AsyncModel, get_model
@@ -453,6 +454,18 @@ class Middleware:
         self._last_state = np.asarray(res.state)
         return res
 
+    def compile_step(self):
+        """Compiles the fused BSP step for the current structure ahead of
+        the first :meth:`run`, which then reuses it, and returns the
+        compiled program (``as_text()``, ``memory_analysis()``)."""
+        if self._fused_kind != "bsp":
+            raise ValueError(
+                "compile_step needs the fused BSP drive loop; this "
+                f"composition runs {self._fused_kind or 'the host loop'}")
+        if self._loop is None:
+            self._loop = DriveLoop(self)
+        return self._loop.compile()
+
     # -- between-iteration structure polling -------------------------------
     def _poll_structure(self, it: int) -> dict:
         """The between-iteration poll of the fused drive loops: feeds
@@ -662,9 +675,8 @@ class Middleware:
             # preserved BlockSet identities keep the daemon's host-side
             # tile caches warm across the migration
             self.blocksets = [self.blocksets[int(i)] for i in perm]
-        devs = np.asarray([self.fleet_devices[d] for d in chosen],
-                          dtype=object)
-        mesh = jax.sharding.Mesh(devs, (self.upper.axis,))
+        mesh = shd.make_mesh((len(chosen),), (self.upper.axis,),
+                             devices=[self.fleet_devices[d] for d in chosen])
         before, self._mesh_device_ids = self._mesh_device_ids, list(chosen)
         record = {
             "killed": [int(d) for d in killed],
@@ -1337,6 +1349,20 @@ class DriveLoop(_FusedLoopBase):
 
     def _init_carry(self, state, active):
         return (state, active)
+
+    def compile(self):
+        """Builds and compiles the step for the current structure; the
+        next :meth:`run` reuses it.  Returns the compiled program."""
+        mw = self.mw
+        state0, aux = mw.program.init(mw.graph)
+        rep = jax.sharding.NamedSharding(mw.daemon.mesh,
+                                         jax.sharding.PartitionSpec())
+        args = (jax.device_put(state0, rep),
+                jax.device_put(np.ones(mw.n, dtype=bool), rep),
+                jax.device_put(aux, rep), jnp.int32(1))
+        self._step = self._build_step()
+        self._epoch_seen = mw.epochs.version
+        return self._step.lower(*args, mw.daemon.stacked).compile()
 
     def _migrate_carry(self, carry):
         # both carries are mesh-replicated — the survivors already hold

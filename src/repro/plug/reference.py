@@ -22,19 +22,21 @@ def run_reference(graph: Graph, program: VertexProgram,
                     else np.ones(graph.num_edges, np.float32))[:, None]
     max_it = max_iterations or program.max_iterations
     n = graph.num_vertices
+    # which vertices receive any message is fixed by the edge list
+    has = jnp.asarray(np.bincount(graph.dst, minlength=n) > 0)[:, None]
 
+    # the edge arrays are arguments, not constants captured by the jit:
+    # at Graph500 scale they would be baked into the compiled program
     @jax.jit
-    def step(state, it):
+    def step(state, it, src, dst, w, aux, has):
         msgs = program.msg_gen(state[src], state[dst], w, aux[src])
         agg = program.monoid.segment_reduce(msgs, dst, n)
-        cnt = jax.ops.segment_sum(jnp.ones_like(dst), dst, n)
-        has = (cnt > 0)[:, None]
         agg = jnp.where(has, agg, jnp.full_like(agg, program.monoid.identity))
         return program.msg_apply(state, agg, has, aux, it)
 
     it = 0
     for it in range(1, max_it + 1):
-        state, active = step(state, it)
+        state, active = step(state, it, src, dst, w, aux, has)
         if not bool(active.any()):
             break
     return np.asarray(state), it

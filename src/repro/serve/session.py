@@ -81,7 +81,8 @@ class GraphServeSession:
 
     def __init__(self, graph: Graph, *, num_shards: int = 8,
                  daemon: str = "sharded", upper: str = "mesh",
-                 kernel: str = "reference", max_batch: int = 8,
+                 kernel: str = "reference", csr_config=None,
+                 max_batch: int = 8,
                  block_size: int | str = "auto",
                  monitor=None, failures=None,
                  analytics_iterations: int = 60):
@@ -93,6 +94,7 @@ class GraphServeSession:
         self.daemon_name = daemon
         self.upper_name = upper
         self.kernel = kernel
+        self.csr_config = csr_config  # pinned CSR kernel config; None tunes
         self.max_batch = int(max_batch)
         self.block_size = block_size
         self.monitor = monitor
@@ -124,7 +126,8 @@ class GraphServeSession:
             return self.daemon_name
         from repro.plug.daemons import get_daemon
 
-        d = get_daemon("sharded", kernel=self.kernel)
+        d = get_daemon("sharded", kernel=self.kernel,
+                       csr_config=self.csr_config)
         donor = self._donor_daemon()
         if donor is not None and hasattr(d, "share_from"):
             d.share_from(donor)

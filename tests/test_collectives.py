@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.dist import collectives as C
+from repro.dist import sharding as shd
 
 
 def test_quantize_roundtrip_error_bound():
@@ -65,7 +66,7 @@ def test_error_feedback_is_unbiased_over_time():
 def test_shard_map_compressed_allreduce_runs():
     """End-to-end on the host mesh (1 device → group of 1, exactness)."""
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = shd.make_mesh((n,), ("data",))
     run = C.make_compressed_allreduce(mesh, "data")
     x = {"g": jnp.arange(n * 8, dtype=jnp.float32).reshape(n * 8)}
     r = {"g": jnp.zeros((n * 8,), jnp.float32)}
@@ -96,7 +97,7 @@ def _host_int8_wire(shards, bits=8):
 @pytest.mark.parametrize("wire", ["int8", "emulated"])
 def test_wire_formats_approximate_true_mean(wire):
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = shd.make_mesh((n,), ("data",))
     run = C.make_compressed_allreduce(mesh, "data", wire=wire)
     rng = np.random.default_rng(7)
     x = jnp.asarray(rng.standard_normal((n * 16,)), jnp.float32)
@@ -116,7 +117,7 @@ def test_int8_wire_matches_host_oracle():
     requantize + int32 accumulate to within one float ulp (XLA may
     reassociate the final dequantize's scale/size multiply)."""
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = shd.make_mesh((n,), ("data",))
     run = C.make_compressed_allreduce(mesh, "data", wire="int8")
     rng = np.random.default_rng(8)
     x_host = rng.standard_normal((n, 32)).astype(np.float32)
@@ -133,7 +134,7 @@ def test_int8_wire_error_feedback_conserves_mass():
     """Over iterations, wire payloads + final residual == inputs (per
     shard), independent of the shared-scale wire format."""
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = shd.make_mesh((n,), ("data",))
     run = C.make_compressed_allreduce(mesh, "data", wire="int8")
     rng = np.random.default_rng(9)
     res = jnp.zeros((n * 8,), jnp.float32)
@@ -152,7 +153,7 @@ def test_int8_wire_error_feedback_conserves_mass():
 
 def test_wire_format_validation():
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = shd.make_mesh((n,), ("data",))
     with pytest.raises(ValueError):
         C.make_compressed_allreduce(mesh, "data", wire="fp4")
 

@@ -31,7 +31,7 @@ from repro.train.step import make_train_step
 
 arch = sys.argv[1]
 cfg = get_reduced(arch)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = shd.make_mesh((4, 2), ("data", "model"))
 rules = shd.make_rules(mesh)
 model = Model(cfg)
 results = {{}}

@@ -10,7 +10,7 @@ from repro.dist import fault, sharding as shd
 @pytest.fixture(scope="module")
 def mesh():
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return shd.make_mesh((1, n), ("data", "model"))
 
 
 def test_spec_divisible(mesh):
