@@ -1,4 +1,4 @@
-"""Benchmark driver: one module per paper table/figure + roofline summary.
+"""The paper benchmarks: one module per paper table/figure.
 
   PYTHONPATH=src python -m benchmarks.run           # all, small settings
   PYTHONPATH=src python -m benchmarks.run --only bench_sync
@@ -18,7 +18,7 @@ def main():
 
     from benchmarks import (bench_accel, bench_balance, bench_cost_ratio,
                             bench_isolation, bench_pipeline,
-                            bench_scalability, bench_sync, roofline)
+                            bench_scalability, bench_sync)
 
     suites = {
         "bench_accel": lambda: bench_accel.run(small=True),        # Fig. 8
@@ -42,11 +42,6 @@ def main():
         except Exception:
             failures.append(name)
             traceback.print_exc()
-    print("\n=== roofline (from dry-run artifacts, if present) ===")
-    try:
-        roofline.main()
-    except Exception:
-        traceback.print_exc()
     if failures:
         print("FAILED:", failures)
         sys.exit(1)

@@ -159,6 +159,7 @@ def csr_tile_pallas(src, aux, row, lsrc, seg, w, emask_f32, *,
         out_specs=[_tile_spec(o.shape) for o in out_shape],
         out_shape=out_shape,
         interpret=interpret,
+        name="csr_tile",
     )(*args)
 
 

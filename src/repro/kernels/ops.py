@@ -184,26 +184,30 @@ def csr_aggregate(state, aux, csr: dict, *, program: VertexProgram,
             src_ids, row_ids = ((csr["gsrc"], csr["gdst"])
                                 if config.gather == "take"
                                 else (csr["svids"], csr["rows"]))
+            with jax.named_scope("plug.gather"):
+                operands = (
+                    _kmajor(state, src_ids), _kmajor(aux, src_ids),
+                    _kmajor(state, row_ids), edge_rows(csr["lsrc"]),
+                    edge_rows(csr["seg"]), edge_rows(w),
+                    edge_rows(emask.astype(jnp.float32)))
             partial, counts = csr_tile_pallas(
-                _kmajor(state, src_ids), _kmajor(aux, src_ids),
-                _kmajor(state, row_ids), edge_rows(csr["lsrc"]),
-                edge_rows(csr["seg"]), edge_rows(w),
-                edge_rows(emask.astype(jnp.float32)),
-                row_tile=csr["rows"].shape[1], program=program,
+                *operands, row_tile=csr["rows"].shape[1], program=program,
                 gather=config.gather, interpret=_default_interpret())
-            partial = jnp.swapaxes(partial, 1, 2)
-            counts = counts.reshape(t, -1)
+            with jax.named_scope("plug.combine.tiles"):
+                partial = jnp.swapaxes(partial, 1, 2)
         else:
             partial, counts = _csr_tiles_xla(
                 state[csr["svids"]], aux[csr["svids"]], state[csr["rows"]],
                 csr["lsrc"], csr["seg"], w, emask, program=program,
                 merge=config.merge, gather=config.gather)
-        # cross-tile combine: finishes split hub rows and folds every
-        # tile's row partials into the shard aggregate
-        rows = csr["rows"].reshape(-1)
-        agg = monoid.segment_reduce(partial.reshape(-1, k), rows, n)
-        cnt = jax.ops.segment_sum(counts.reshape(-1), rows, n)
-    agg = jnp.where((cnt > 0)[:, None], agg, monoid.identity)
+        with jax.named_scope("plug.combine.tiles"):
+            # cross-tile combine: finishes split hub rows and folds every
+            # tile's row partials into the shard aggregate
+            rows = csr["rows"].reshape(-1)
+            agg = monoid.segment_reduce(partial.reshape(-1, k), rows, n)
+            cnt = jax.ops.segment_sum(counts.reshape(-1), rows, n)
+    with jax.named_scope("plug.combine.tiles"):
+        agg = jnp.where((cnt > 0)[:, None], agg, monoid.identity)
     return agg, cnt
 
 

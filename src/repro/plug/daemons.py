@@ -44,6 +44,7 @@ from repro.core import pipeline as pl
 from repro.core.blocks import BlockSet
 from repro.core.pow2 import pad_pow2
 from repro.core.template import VertexProgram
+from repro.plug.spans import build_span
 
 KERNELS = ("reference", "pallas")
 
@@ -394,7 +395,8 @@ class ShardedDaemon(VectorizedDaemon):
         devices.
         """
         self._setup_shard_mesh(blocksets, mesh, axis)
-        host = self._host_block_stacks(blocksets)
+        with build_span("plug.build.blocks"):
+            host = self._host_block_stacks(blocksets)
         place = self._place_stack
 
         # Digest-verified adoption (see share_from): a field whose
@@ -410,19 +412,25 @@ class ShardedDaemon(VectorizedDaemon):
         self.adopted_fields = 0
 
         def place_or_adopt(name, a):
-            d = hashlib.sha1(np.ascontiguousarray(a).tobytes()).hexdigest()
-            self._stacked_digests[name] = d
-            if donor_ok and donor._stacked_digests.get(name) == d:
-                adopted = _stacked_field(donor._stacked, name)
-                if adopted is not None and tuple(adopted.shape) == a.shape:
-                    self.adopted_fields += 1
-                    return adopted
-            return place(a)
+            with build_span("plug.build.place"):
+                d = hashlib.sha1(
+                    np.ascontiguousarray(a).tobytes()).hexdigest()
+                self._stacked_digests[name] = d
+                if donor_ok and donor._stacked_digests.get(name) == d:
+                    adopted = _stacked_field(donor._stacked, name)
+                    if (adopted is not None
+                            and tuple(adopted.shape) == a.shape):
+                        self.adopted_fields += 1
+                        return adopted
+                return place(a)
 
         self._stacked = {k: place_or_adopt(k, a) for k, a in host.items()}
         if self.kernel == "pallas":
             self._stacked["csr"] = self._stack_csr_tiles(blocksets,
                                                          place_or_adopt)
+        # the transfers overlap the host work above; one wait at the end
+        with build_span("plug.build.place"):
+            jax.block_until_ready(self._stacked)
         self._partials_fns = {}
         self._oocore_config = None
         self._super_shards = None
@@ -486,6 +494,7 @@ class ShardedDaemon(VectorizedDaemon):
         return jax.device_put(
             a, shd.sharding_for(a.shape, axes, self.mesh, rules))
 
+    @build_span("plug.build.tiles")
     def _stack_csr_tiles(self, blocksets, place):
         """Compacts every shard's blockset into CSR tiles, pads them to a
         common (nt, RT, ST) envelope and places the stacked arrays.
@@ -703,14 +712,16 @@ class ShardedDaemon(VectorizedDaemon):
                     gsrc, gdst):
             # local slices (S/m, nt, …); state/aux replicated
             s_l, nt, et = lsrc.shape
-            if use_frontier:
-                # per-edge frontier filtering — trajectory-identical to
-                # the block path's block-granularity skipping for the
-                # idempotent monoids that drive frontiers
-                em = emask & act[gsrc]
-            else:
-                em = emask
-            tiles_run = jnp.any(em, axis=2).sum(axis=1).astype(jnp.int32)
+            with jax.named_scope("plug.gather"):
+                if use_frontier:
+                    # per-edge frontier filtering — trajectory-identical
+                    # to the block path's block-granularity skipping for
+                    # the idempotent monoids that drive frontiers
+                    em = emask & act[gsrc]
+                else:
+                    em = emask
+                tiles_run = jnp.any(em, axis=2).sum(axis=1).astype(
+                    jnp.int32)
             csr = {
                 "rows": rows.reshape(s_l * nt, -1),
                 "seg": seg.reshape(s_l * nt, et),

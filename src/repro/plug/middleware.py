@@ -64,6 +64,7 @@ from repro.dist import fault as dist_fault
 from repro.dist import sharding as shd
 from repro.graph import mutation as graph_mutation
 from repro.graph.structure import EdgePartition, Graph
+from repro.plug import spans
 from repro.plug.computation import BSP, GAS, AsyncModel, get_model
 from repro.plug.daemons import get_daemon
 from repro.plug.epoch import StructureEpoch, StructureEpochBus
@@ -214,40 +215,48 @@ class Middleware:
         self.model = get_model(model) if isinstance(model, str) else model
 
         self._owns_partitions = partitions is None
-        if partitions is None:
-            if capacities is not None:
-                c = np.asarray(capacities, dtype=np.float64)
-                if c.shape != (num_shards,):
-                    raise ValueError(
-                        f"capacities must have shape ({num_shards},), got "
-                        f"{c.shape}")
-                partitions = self.upper.partition(
-                    graph, num_shards, fractions=lemma2_fractions(c))
-            else:
-                partitions = self.upper.partition(graph, num_shards)
-        self.partitions = list(partitions)
-        self.num_shards = len(self.partitions)
-        self.n = graph.num_vertices
-        self.k = program.state_width
-        self._setup_blocks()
+        # the build's phases, kept as (name, start_ns, end_ns, parent)
+        # (plug/spans.py); the spans in the daemon's bind land here too
+        with spans.recording() as self.build_spans:
+            if partitions is None:
+                with spans.build_span("plug.build.partition"):
+                    if capacities is not None:
+                        c = np.asarray(capacities, dtype=np.float64)
+                        if c.shape != (num_shards,):
+                            raise ValueError(
+                                f"capacities must have shape ({num_shards},)"
+                                f", got {c.shape}")
+                        partitions = self.upper.partition(
+                            graph, num_shards, fractions=lemma2_fractions(c))
+                    else:
+                        partitions = self.upper.partition(graph, num_shards)
+            self.partitions = list(partitions)
+            self.num_shards = len(self.partitions)
+            self.n = graph.num_vertices
+            self.k = program.state_width
+            with spans.build_span("plug.build.blocks"):
+                self._setup_blocks()
 
-        self.daemon.bind(program, self.n)
-        self.upper.bind(program, self.num_shards)
-        self._apply_fn = make_apply_fn(program)
-        self.stats = SyncStats()
-        self._caches: list[LRUVertexCache] = []  # created per-run by run()
-        self._estimator = CapacityEstimator(self.num_shards)
-        self._fused_kind = self._detect_fused()
-        self._fused = self._fused_kind is not None
-        self.oocore_stats: dict = {}
-        if self._fused_kind == "oocore":
-            self.daemon.bind_super_shards(self.blocksets,
-                                          mesh=self.upper.mesh,
-                                          axis=self.upper.axis,
-                                          config=self.oocore)
-        elif self._fused:
-            self.daemon.bind_shards(self.blocksets, mesh=self.upper.mesh,
-                                    axis=self.upper.axis)
+            self.daemon.bind(program, self.n)
+            self.upper.bind(program, self.num_shards)
+            self._apply_fn = make_apply_fn(program)
+            self.stats = SyncStats()
+            self._caches: list[LRUVertexCache] = []  # created per run
+            self._estimator = CapacityEstimator(self.num_shards)
+            self._fused_kind = self._detect_fused()
+            self._fused = self._fused_kind is not None
+            self.oocore_stats: dict = {}
+            if self._fused_kind == "oocore":
+                self.daemon.bind_super_shards(self.blocksets,
+                                              mesh=self.upper.mesh,
+                                              axis=self.upper.axis,
+                                              config=self.oocore)
+            elif self._fused:
+                self.daemon.bind_shards(self.blocksets, mesh=self.upper.mesh,
+                                        axis=self.upper.axis)
+            if self._fused_kind == "bsp":
+                with spans.build_span("plug.build.place"):
+                    self._place_out_degree()
         self._loop = None
 
         # -- elastic fault tolerance (DESIGN.md §4.4) ----------------------
@@ -338,6 +347,8 @@ class Middleware:
                 self.daemon.remesh(new.mesh, blocksets=list(new.blocksets))
             if self._fused_kind == "oocore":
                 new.oocore_plan = self.daemon.oocore_plan
+            if self._fused_kind == "bsp":
+                self._place_out_degree()
         else:
             prune = getattr(self.daemon, "prune_block_caches", None)
             if prune is not None:
@@ -363,6 +374,17 @@ class Middleware:
             best_b, _ = pl.optimal_integer_blocks(d, o.k1, o.k2, o.k3, o.a)
             return int(min(max(best_b, 64), 1 << 16))
         return int(o.block_size)
+
+    def _place_out_degree(self) -> None:
+        """Places the live arcs' (N,) int32 out-degree, replicated on the
+        daemon's mesh: the fused BSP step's dot of it with the frontier
+        is each iteration record's ``edges_active``."""
+        deg = sum(np.bincount(p.src, minlength=self.n)
+                  for p in self.partitions)
+        self.out_degree = jax.device_put(
+            np.asarray(deg, np.int32),
+            jax.sharding.NamedSharding(self.daemon.mesh,
+                                       jax.sharding.PartitionSpec()))
 
     def _setup_blocks(self) -> None:
         b = self._resolve_block_size()
@@ -464,7 +486,7 @@ class Middleware:
                 f"composition runs {self._fused_kind or 'the host loop'}")
         if self._loop is None:
             self._loop = DriveLoop(self)
-        return self._loop.compile()
+        return self._loop.lower().compile()
 
     # -- between-iteration structure polling -------------------------------
     def _poll_structure(self, it: int) -> dict:
@@ -1224,6 +1246,13 @@ class _FusedLoopBase:
 
     def run(self, max_iterations: int | None = None, *,
             init=None, frontier=None) -> Result:
+        # host spans: plug.run > plug.iteration > plug.poll,
+        # plug.dispatch, plug.fetch; then plug.result.  On a profiler's
+        # clock a device-idle gap lands on the host work around it.
+        with jax.profiler.TraceAnnotation("plug.run"):
+            return self._run(max_iterations, init, frontier)
+
+    def _run(self, max_iterations, init, frontier) -> Result:
         mw = self.mw
         prog = mw.program
         mw.upper.reset()
@@ -1256,49 +1285,57 @@ class _FusedLoopBase:
         converged = False
 
         for it in range(1, max_it + 1):
-            # Structure check between fused iterations: a device killed
-            # (or a mutation batch due) "at iteration k" lands before
-            # iteration k executes.  The poll publishes epochs; the loop
-            # reacts to the bus VERSION — it never remeshes or replans
-            # anything itself — and the run resumes from the carried
-            # (replicated) state: no checkpoint.
-            ev = mw._poll_structure(it)
-            if mw.epochs.version != self._epoch_seen:
-                t_reb = time.perf_counter()
-                carry, aux_dev = self._adopt_epoch(carry, aux_dev,
-                                                   init_fn)
-                self._step = self._build_step()  # new structure → new program
-                stacked = mw.daemon.stacked
-                self._epoch_seen = mw.epochs.version
-                blocks_total = int(sum(bs.num_blocks
-                                       for bs in mw.blocksets))
-                reb_s = time.perf_counter() - t_reb
-                for r in ev.values():  # charge the rebuild to its trigger
-                    if "seconds" in r:
-                        r["seconds"] += reb_s
-                        break
-            carry, done, n_active, blocks_run, extra = self._advance(
-                carry, aux_dev, jnp.int32(it), stacked)
-            mw.stats.rounds_total += 1
-            # ONE host sync per iteration: every record scalar (including
-            # whatever the subclass put in extra) rides the same fetch —
-            # per-key float()/int() casts would each block on the device
-            done, n_active, blocks_run, extra = jax.device_get(
-                (done, n_active, blocks_run, extra))
-            shard_blocks = [int(x) for x in blocks_run]
-            rec = {"iteration": it, "fused": True,
-                   "blocks_total": blocks_total,
-                   "blocks_run": int(sum(shard_blocks)),
-                   "shard_blocks_run": shard_blocks,
-                   "active": int(n_active)}
-            rec.update(ev)
-            rec.update({k: _rec_value(v) for k, v in extra.items()})
-            per_iter.append(rec)
-            if bool(done):
-                converged = True
-                break
+            with jax.profiler.TraceAnnotation("plug.iteration", it=it):
+                # Structure check between fused iterations: a device
+                # killed (or a mutation batch due) "at iteration k" lands
+                # before iteration k executes.  The poll publishes
+                # epochs; the loop reacts to the bus VERSION — it never
+                # remeshes or replans anything itself — and the run
+                # resumes from the carried (replicated) state: no
+                # checkpoint.
+                with jax.profiler.TraceAnnotation("plug.poll"):
+                    ev = mw._poll_structure(it)
+                    if mw.epochs.version != self._epoch_seen:
+                        t_reb = time.perf_counter()
+                        carry, aux_dev = self._adopt_epoch(carry, aux_dev,
+                                                           init_fn)
+                        # new structure → new program
+                        self._step = self._build_step()
+                        stacked = mw.daemon.stacked
+                        self._epoch_seen = mw.epochs.version
+                        blocks_total = int(sum(bs.num_blocks
+                                               for bs in mw.blocksets))
+                        reb_s = time.perf_counter() - t_reb
+                        for r in ev.values():  # charge it to its trigger
+                            if "seconds" in r:
+                                r["seconds"] += reb_s
+                                break
+                with jax.profiler.TraceAnnotation("plug.dispatch"):
+                    carry, done, n_active, blocks_run, extra = self._advance(
+                        carry, aux_dev, jnp.int32(it), stacked)
+                mw.stats.rounds_total += 1
+                # ONE host sync per iteration: every record scalar
+                # (including whatever the subclass put in extra) rides the
+                # same fetch — per-key float()/int() casts would each
+                # block on the device
+                with jax.profiler.TraceAnnotation("plug.fetch"):
+                    done, n_active, blocks_run, extra = jax.device_get(
+                        (done, n_active, blocks_run, extra))
+                shard_blocks = [int(x) for x in blocks_run]
+                rec = {"iteration": it, "fused": True,
+                       "blocks_total": blocks_total,
+                       "blocks_run": int(sum(shard_blocks)),
+                       "shard_blocks_run": shard_blocks,
+                       "active": int(n_active)}
+                rec.update(ev)
+                rec.update({k: _rec_value(v) for k, v in extra.items()})
+                per_iter.append(rec)
+                if bool(done):
+                    converged = True
+                    break
 
-        final = np.asarray(carry[0])  # the run's single device→host transfer
+        with jax.profiler.TraceAnnotation("plug.result"):
+            final = np.asarray(carry[0])  # the run's one device→host copy
         return Result(
             state=final,
             iterations=it,
@@ -1317,8 +1354,9 @@ class DriveLoop(_FusedLoopBase):
     cross-device partial merge, Apply, and the convergence check into a
     single device program.  Vertex state and the frontier stay resident
     on the mesh between iterations; only scalars (converged flag, active
-    count) and the tiny per-shard blocks-run vector cross to the host,
-    and the final state is materialized exactly once after the loop.
+    count, ``edges_active``: the arcs out of the frontier the step ran
+    on) and the tiny per-shard blocks-run vector cross to the host, and
+    the final state is materialized exactly once after the loop.
 
     Because the collective merge is *inside* every step, shard replicas
     never diverge: there is no candidate apply, no sync round to skip,
@@ -1335,24 +1373,33 @@ class DriveLoop(_FusedLoopBase):
         use_frontier = (mw.program.frontier_driven
                         and mw.options.frontier_block_skipping)
 
-        def step(state, active, aux, it, stacked):
+        def step(state, active, aux, it, structure):
+            stacked, out_degree = structure
             partials, counts, blocks_run = daemon.run_all_shards(
                 state, aux, active if use_frontier else None,
                 stacked=stacked)
             agg, cnt = upper.merge_partials(partials, counts)
-            # base == state: replicas are merged every step, never diverge
-            new_state, new_active = apply_fn(state, agg, cnt > 0, aux, it)
-            n_active = new_active.sum()
-            return new_state, new_active, n_active == 0, n_active, blocks_run
+            with jax.named_scope("plug.apply"):
+                # base == state: replicas are merged every step, never
+                # diverge
+                new_state, new_active = apply_fn(state, agg, cnt > 0, aux,
+                                                 it)
+                n_active = new_active.sum()
+            with jax.named_scope("plug.gather"):
+                # arcs out of the frontier the step ran on
+                edges_active = (jnp.where(active, out_degree, 0).sum()
+                                if use_frontier else out_degree.sum())
+            return (new_state, new_active, n_active == 0, n_active,
+                    (blocks_run, edges_active))
 
         return jax.jit(step)
 
     def _init_carry(self, state, active):
         return (state, active)
 
-    def compile(self):
-        """Builds and compiles the step for the current structure; the
-        next :meth:`run` reuses it.  Returns the compiled program."""
+    def lower(self):
+        """Builds the step for the current structure and lowers it; the
+        next :meth:`run` reuses the step."""
         mw = self.mw
         state0, aux = mw.program.init(mw.graph)
         rep = jax.sharding.NamedSharding(mw.daemon.mesh,
@@ -1362,7 +1409,7 @@ class DriveLoop(_FusedLoopBase):
                 jax.device_put(aux, rep), jnp.int32(1))
         self._step = self._build_step()
         self._epoch_seen = mw.epochs.version
-        return self._step.lower(*args, mw.daemon.stacked).compile()
+        return self._step.lower(*args, (mw.daemon.stacked, mw.out_degree))
 
     def _migrate_carry(self, carry):
         # both carries are mesh-replicated — the survivors already hold
@@ -1370,9 +1417,10 @@ class DriveLoop(_FusedLoopBase):
         return tuple(self.mw.upper.migrate(list(carry)))
 
     def _advance(self, carry, aux, it, stacked):
-        state, active, done, n_active, blocks_run = self._step(
-            *carry, aux, it, stacked)
-        return (state, active), done, n_active, blocks_run, {}
+        state, active, done, n_active, (blocks_run, edges) = self._step(
+            *carry, aux, it, (stacked, self.mw.out_degree))
+        return ((state, active), done, n_active, blocks_run,
+                {"edges_active": edges})
 
 
 class OocoreDriveLoop(_FusedLoopBase):

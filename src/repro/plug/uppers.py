@@ -330,9 +330,12 @@ class MeshUpperSystem(HostUpperSystem):
         if self.wire != "exact":
             raise ValueError("merge_partials supports wire='exact' only; "
                              "compressed merges take the classic path")
+        import jax
+
         if self._pmerge_fn is None:
             self._pmerge_fn = self._build_pmerge()
-        return self._pmerge_fn(partials, counts)
+        with jax.named_scope("plug.combine.devices"):
+            return self._pmerge_fn(partials, counts)
 
     def merge_partials_async(self, fresh_p, fresh_c, held_p, held_c,
                              theta, floor, run_mask=None):
