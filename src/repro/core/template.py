@@ -30,9 +30,11 @@ keeps every block a fixed shape, so one compiled program serves all blocks.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 
@@ -132,6 +134,31 @@ class VertexProgram:
     # answers are bit-identical to B independent single-query runs.
     num_queries: int = 0
     query_activity: Callable[..., jnp.ndarray] | None = None
+
+    @functools.cached_property
+    def msg_gen_reads(self) -> frozenset[str]:
+        """The ``msg_gen`` operands its messages depend on: a subset of
+        ``{"src", "dst", "weight", "aux"}``.
+
+        Traced once per program on small abstract shapes and walked
+        backwards from the outputs: an equation with a live output (or an
+        effect) makes all of its inputs live, which is conservative for
+        sub-jaxprs.  The Pallas tile path gathers per edge only what is
+        read here; an operand left out reaches ``msg_gen`` as zeros of
+        its usual shape.
+        """
+        k, a = self.state_width, max(self.aux_width, 1)
+        shapes = [(8, k), (8, k), (8, 1), (8, a)]
+        closed = jax.make_jaxpr(self.msg_gen)(
+            *(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes))
+        jaxpr = closed.jaxpr
+        live = {v for v in jaxpr.outvars if isinstance(v, jex_core.Var)}
+        for eqn in reversed(jaxpr.eqns):
+            if eqn.effects or any(v in live for v in eqn.outvars):
+                live.update(v for v in eqn.invars
+                            if isinstance(v, jex_core.Var))
+        return frozenset(name for name, v in zip(
+            ("src", "dst", "weight", "aux"), jaxpr.invars) if v in live)
 
     def supports_sync_skipping(self) -> bool:
         return self.monoid.idempotent

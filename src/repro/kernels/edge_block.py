@@ -64,16 +64,22 @@ def _onehot_dot(onehot, table):
     return out
 
 
-def _tile_kernel(src_ref, aux_ref, row_ref, lsrc_ref, seg_ref, w_ref,
-                 emask_ref, partial_ref, counts_ref, *,
-                 program: VertexProgram, gather: str):
+def _tile_kernel(*refs, program: VertexProgram, gather: str,
+                 has_aux: bool, has_row: bool):
     """One grid step = one edge tile: gather, Gen per edge, Merge per row.
 
-    ``seg`` is the tile-local row of every edge.  Where it is sorted and
-    every low-degree row lives inside one tile (degree bucketing), the
-    per-row merge here is final for those rows; split hub rows are
-    finished by the cross-tile segmented combine in ops.csr_aggregate.
+    ``refs`` are the operands of :func:`csr_tile_pallas` that were given
+    (``aux`` and ``row`` may be absent), then the two outputs.  ``seg``
+    is the tile-local row of every edge.  Where it is sorted and every
+    low-degree row lives inside one tile (degree bucketing), the per-row
+    merge here is final for those rows; split hub rows are finished by
+    the cross-tile segmented combine in ops.csr_aggregate.
     """
+    refs = list(refs)
+    src_ref = refs.pop(0)
+    aux_ref = refs.pop(0) if has_aux else None
+    row_ref = refs.pop(0) if has_row else None
+    lsrc_ref, seg_ref, w_ref, emask_ref, partial_ref, counts_ref = refs
     monoid = program.monoid
     if monoid.name not in _MERGE_MONOIDS:
         # trace-time check, same contract as Monoid.segment_reduce /
@@ -89,15 +95,25 @@ def _tile_kernel(src_ref, aux_ref, row_ref, lsrc_ref, seg_ref, w_ref,
     rt = partial_ref.shape[1]
     seg_col = seg.T                         # (ET, 1)
     row_oh = seg_col == lax.broadcasted_iota(jnp.int32, (et, rt), 1)
+    # an operand msg_gen does not read is not handed in: it reaches
+    # msg_gen as zeros of the shape it would have had
+    sa = jnp.zeros((et, max(program.aux_width, 1)), jnp.float32)
+    d = jnp.zeros((et, k), jnp.float32)
     if gather == "onehot":
         st = src_ref.shape[1]
         src_oh = lsrc_ref[...].T == lax.broadcasted_iota(
             jnp.int32, (et, st), 1)                         # (ET, ST)
         s = _onehot_dot(src_oh, src_ref[...].T)             # (ET, K)
-        sa = _onehot_dot(src_oh, aux_ref[...].T)            # (ET, A)
-        d = _onehot_dot(row_oh, row_ref[...].T)             # (ET, K)
+        if has_aux:
+            sa = _onehot_dot(src_oh, aux_ref[...].T)        # (ET, A)
+        if has_row:
+            d = _onehot_dot(row_oh, row_ref[...].T)         # (ET, K)
     else:  # "take": gathered per edge ahead of the kernel
-        s, sa, d = src_ref[...].T, aux_ref[...].T, row_ref[...].T
+        s = src_ref[...].T
+        if has_aux:
+            sa = aux_ref[...].T
+        if has_row:
+            d = row_ref[...].T
 
     msgs = program.msg_gen(s, d, w_ref[...].T, sa)          # (ET, K)
 
@@ -139,6 +155,9 @@ def csr_tile_pallas(src, aux, row, lsrc, seg, w, emask_f32, *,
         blocks; row (T, K, RT) — per-tile row (dst) state blocks.
       gather="take": src (T, K, ET), aux (T, A, ET), row (T, K, ET) —
         the same values already gathered per edge.
+      aux and row may be None where ``msg_gen`` does not read them
+        (``VertexProgram.msg_gen_reads``): no operand, no DMA, and
+        ``msg_gen`` gets zeros of the (ET, A) / (ET, K) shape instead.
       lsrc/seg (T, 1, ET) i32, w (T, 1, ET) f32, emask_f32 (T, 1, ET) f32.
       row_tile: RT, the row-block width of the outputs.
     Returns: partial (T, K, RT) f32, counts (T, 1, RT) i32 — per-tile row
@@ -148,10 +167,13 @@ def csr_tile_pallas(src, aux, row, lsrc, seg, w, emask_f32, *,
     if gather not in ("take", "onehot"):
         raise ValueError(f"gather must be 'take' or 'onehot', got {gather!r}")
     t, k, _ = src.shape
-    kern = functools.partial(_tile_kernel, program=program, gather=gather)
+    kern = functools.partial(_tile_kernel, program=program, gather=gather,
+                             has_aux=aux is not None,
+                             has_row=row is not None)
     out_shape = [jax.ShapeDtypeStruct((t, k, row_tile), jnp.float32),
                  jax.ShapeDtypeStruct((t, 1, row_tile), jnp.int32)]
-    args = (src, aux, row, lsrc, seg, w, emask_f32)
+    args = tuple(a for a in (src, aux, row, lsrc, seg, w, emask_f32)
+                 if a is not None)
     return pl.pallas_call(
         kern,
         grid=(t,),

@@ -39,6 +39,23 @@ def _kmajor(table, ids):
     return jnp.swapaxes(table[ids], 1, 2)
 
 
+def _one_column_at_least(aux):
+    """A zero-width aux as one column of zeros: gathers and BlockSpecs
+    need dims >= 1."""
+    if aux.shape[1]:
+        return aux
+    return jnp.zeros((aux.shape[0], 1), aux.dtype)
+
+
+def tile_gathers(program: VertexProgram) -> tuple[str, ...]:
+    """The per-edge operands ``csr_aggregate``'s Pallas path gathers for
+    ``program``: its src state always, its dst state and its aux only
+    where ``msg_gen`` reads them (``VertexProgram.msg_gen_reads``).
+    Each is gathered under its own scope inside ``plug.gather``."""
+    reads = program.msg_gen_reads
+    return ("src",) + tuple(op for op in ("dst", "aux") if op in reads)
+
+
 # --------------------------------------------------------------------------
 # edge_block
 # --------------------------------------------------------------------------
@@ -50,8 +67,7 @@ def edge_block_aggregate(state, aux, vids, lsrc, ldst, w, emask, *,
     if impl == "reference":
         return ref.edge_block_aggregate(state, aux, vids, lsrc, ldst, w,
                                         emask, program=program)
-    if aux.shape[1] == 0:  # zero-width aux: Pallas BlockSpecs need dims >= 1
-        aux = jnp.zeros((state.shape[0], 1), state.dtype)
+    aux = _one_column_at_least(aux)
     nb, b = lsrc.shape
     vstate = _kmajor(state, vids)  # agent "download" into block layout
 
@@ -157,10 +173,9 @@ def csr_aggregate(state, aux, csr: dict, *, program: VertexProgram,
     k = program.state_width
     n = num_vertices
     emask = csr["emask"]
-    if aux.shape[1] == 0:  # zero-width aux: keep gathers/BlockSpecs ≥ 1 wide
-        aux = jnp.zeros((state.shape[0], 1), state.dtype)
     w = csr["w"].astype(jnp.float32)
     if config.merge == "flat":
+        aux = _one_column_at_least(aux)
         gsrc = csr["gsrc"].reshape(-1)
         gdst = csr["gdst"].reshape(-1)
         emf = emask.reshape(-1)
@@ -184,18 +199,29 @@ def csr_aggregate(state, aux, csr: dict, *, program: VertexProgram,
             src_ids, row_ids = ((csr["gsrc"], csr["gdst"])
                                 if config.gather == "take"
                                 else (csr["svids"], csr["rows"]))
+            # only what msg_gen reads is gathered: the kernel hands it
+            # zeros in place of an absent aux or row operand
+            gathers = tile_gathers(program)
+            aux_e = row_e = None
             with jax.named_scope("plug.gather"):
-                operands = (
-                    _kmajor(state, src_ids), _kmajor(aux, src_ids),
-                    _kmajor(state, row_ids), edge_rows(csr["lsrc"]),
-                    edge_rows(csr["seg"]), edge_rows(w),
-                    edge_rows(emask.astype(jnp.float32)))
+                with jax.named_scope("src"):
+                    src_e = _kmajor(state, src_ids)
+                if "dst" in gathers:
+                    with jax.named_scope("dst"):
+                        row_e = _kmajor(state, row_ids)
+                if "aux" in gathers:
+                    with jax.named_scope("aux"):
+                        aux_e = _kmajor(_one_column_at_least(aux), src_ids)
+                edges = (edge_rows(csr["lsrc"]), edge_rows(csr["seg"]),
+                         edge_rows(w), edge_rows(emask.astype(jnp.float32)))
             partial, counts = csr_tile_pallas(
-                *operands, row_tile=csr["rows"].shape[1], program=program,
+                src_e, aux_e, row_e, *edges,
+                row_tile=csr["rows"].shape[1], program=program,
                 gather=config.gather, interpret=_default_interpret())
             with jax.named_scope("plug.combine.tiles"):
                 partial = jnp.swapaxes(partial, 1, 2)
         else:
+            aux = _one_column_at_least(aux)
             partial, counts = _csr_tiles_xla(
                 state[csr["svids"]], aux[csr["svids"]], state[csr["rows"]],
                 csr["lsrc"], csr["seg"], w, emask, program=program,

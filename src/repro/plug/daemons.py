@@ -344,6 +344,9 @@ class ShardedDaemon(VectorizedDaemon):
         self._tile_cache: dict = {}
         self.tiles_recut = 0
         self.tilesets_reused = 0
+        # the per-edge gathers of the last CSR step built with the Pallas
+        # tile kernel, as named by the device scopes inside plug.gather
+        self.edge_gathers: tuple[str, ...] = ()
         # masked execution (MaskCapableDaemon): vertex-level priority
         # buckets + Gen-invocation instrumentation.  ``instrument`` adds
         # a host callback to the cond-guarded shard body, so the counters
@@ -707,6 +710,9 @@ class ShardedDaemon(VectorizedDaemon):
         program = self.program
         n = self.n
         cfg = self._csr_config
+        if cfg.lowering == "pallas" and cfg.merge != "flat":
+            self.edge_gathers = (("frontier",) * use_frontier
+                                 + kops.tile_gathers(program))
 
         def compute(state, aux, act, rows, seg, lsrc, svids, w, emask,
                     gsrc, gdst):
@@ -717,7 +723,8 @@ class ShardedDaemon(VectorizedDaemon):
                     # per-edge frontier filtering — trajectory-identical
                     # to the block path's block-granularity skipping for
                     # the idempotent monoids that drive frontiers
-                    em = emask & act[gsrc]
+                    with jax.named_scope("frontier"):
+                        em = emask & act[gsrc]
                 else:
                     em = emask
                 tiles_run = jnp.any(em, axis=2).sum(axis=1).astype(

@@ -72,6 +72,27 @@ def test_csr_tile_kernel_compiles_for_v5e(one_chip, monoid, gather):
 
 
 @pytest.mark.parametrize("gather", ["take", "onehot"])
+@pytest.mark.parametrize("monoid", ["sum", "min"])
+def test_csr_tile_kernel_without_aux_and_row_compiles_for_v5e(
+        one_chip, monoid, gather):
+    """The kernel as a program that reads neither its aux nor its dst
+    state gets it: src and the per-edge vectors only."""
+    prog = _program(monoid)
+    width = ST if gather == "onehot" else ET
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (s((T, prog.state_width, width)), s((T, 1, ET), jnp.int32),
+            s((T, 1, ET), jnp.int32), s((T, 1, ET)), s((T, 1, ET)))
+    text = _compiled_text(
+        lambda src, *edges: csr_tile_pallas(
+            src, None, None, *edges, row_tile=RT, program=prog,
+            gather=gather, interpret=False), args)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("gather", ["take", "onehot"])
 def test_csr_aggregate_compiles_for_v5e(one_chip, monkeypatch, gather):
     """The daemon's whole tile aggregate — K-major gathers, the kernel,
     the cross-tile combine — as the fused step traces it on a TPU."""

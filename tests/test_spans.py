@@ -7,11 +7,13 @@ the build open host spans (``jax.profiler.TraceAnnotation``); the build
 keeps its spans in ``Middleware.build_spans``; each iteration record
 counts the arcs out of the step's frontier (``edges_active``).
 """
+import dataclasses
 import glob
 import os
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -26,6 +28,7 @@ SCOPES = ["plug.gather", "plug.combine.tiles", "plug.combine.devices",
 BUILD_SPANS = {"plug.build.partition", "plug.build.blocks",
                "plug.build.tiles", "plug.build.place"}
 SOURCES = [0, 1, 2, 3]
+EDGE_GATHERS = ["frontier", "src", "dst", "aux"]  # scopes in plug.gather
 
 
 def _middleware(graph, program):
@@ -45,14 +48,41 @@ def small():
 
 
 @pytest.fixture(scope="module")
-def step_text(small):
+def sssp_step(small):
     mw = _middleware(small, sssp_bf(small, SOURCES))
-    return DriveLoop(mw).lower().as_text(debug_info=True)
+    return mw.daemon, DriveLoop(mw).lower().as_text(debug_info=True)
+
+
+@pytest.fixture(scope="module")
+def step_text(sssp_step):
+    return sssp_step[1]
 
 
 @pytest.mark.parametrize("name", SCOPES + ["csr_tile"])
 def test_lowered_step_names_each_scope_and_the_kernel(step_text, name):
     assert name in step_text
+
+
+def _gather_scopes(text) -> set:
+    return {g for g in EDGE_GATHERS if f"plug.gather/{g}/" in text}
+
+
+def test_sssp_step_gathers_no_aux_and_no_dst_state(sssp_step):
+    """SSSP's messages read the src state and the weight: the step
+    gathers the frontier and the src state per edge, nothing more."""
+    daemon, text = sssp_step
+    assert _gather_scopes(text) == {"frontier", "src"}
+    assert set(daemon.edge_gathers) == {"frontier", "src"}
+
+
+def test_a_step_reading_dst_state_gathers_it(small):
+    prog = dataclasses.replace(
+        sssp_bf(small, SOURCES), name="sssp_bounded",
+        msg_gen=lambda s, d, w, a: jnp.minimum(s + w, d))
+    mw = _middleware(small, prog)
+    text = DriveLoop(mw).lower().as_text(debug_info=True)
+    assert _gather_scopes(text) == {"frontier", "src", "dst"}
+    assert mw.daemon.edge_gathers == ("frontier", "src", "dst")
 
 
 def _self_seconds(build_spans) -> dict:
