@@ -148,7 +148,8 @@ def _time_config(src, dst, weights, num_vertices, program, config, *,
     @jax.jit
     def run(state, aux, csr):
         return ops.csr_aggregate(state, aux, csr, program=program,
-                                 num_vertices=num_vertices, config=config)
+                                 num_vertices=num_vertices,
+                                 config=config)[:2]
 
     agg, cnt = run(state, aux, csr)  # compile + warm up
     jax.block_until_ready((agg, cnt))

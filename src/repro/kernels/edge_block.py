@@ -65,7 +65,7 @@ def _onehot_dot(onehot, table):
 
 
 def _tile_kernel(*refs, program: VertexProgram, gather: str,
-                 has_aux: bool, has_row: bool):
+                 has_aux: bool, has_row: bool, frontier: bool):
     """One grid step = one edge tile: gather, Gen per edge, Merge per row.
 
     ``refs`` are the operands of :func:`csr_tile_pallas` that were given
@@ -73,7 +73,10 @@ def _tile_kernel(*refs, program: VertexProgram, gather: str,
     is the tile-local row of every edge.  Where it is sorted and every
     low-degree row lives inside one tile (degree bucketing), the per-row
     merge here is final for those rows; split hub rows are finished by
-    the cross-tile segmented combine in ops.csr_aggregate.
+    the cross-tile segmented combine in ops.csr_aggregate.  With
+    ``frontier`` the src operand carries one more row after the K state
+    rows, the source's activity (1.0 or 0.0), and an edge is live where
+    both it and ``emask`` are.
     """
     refs = list(refs)
     src_ref = refs.pop(0)
@@ -103,7 +106,7 @@ def _tile_kernel(*refs, program: VertexProgram, gather: str,
         st = src_ref.shape[1]
         src_oh = lsrc_ref[...].T == lax.broadcasted_iota(
             jnp.int32, (et, st), 1)                         # (ET, ST)
-        s = _onehot_dot(src_oh, src_ref[...].T)             # (ET, K)
+        s = _onehot_dot(src_oh, src_ref[...].T)             # (ET, K[+1])
         if has_aux:
             sa = _onehot_dot(src_oh, aux_ref[...].T)        # (ET, A)
         if has_row:
@@ -114,6 +117,10 @@ def _tile_kernel(*refs, program: VertexProgram, gather: str,
             sa = aux_ref[...].T
         if has_row:
             d = row_ref[...].T
+    if frontier:
+        # split after the gather: one row per edge either way
+        emask_f = emask_f * s[:, k:].T                      # (1, ET)
+        s = s[:, :k]
 
     msgs = program.msg_gen(s, d, w_ref[...].T, sa)          # (ET, K)
 
@@ -147,7 +154,7 @@ def _tile_spec(shape):
 
 def csr_tile_pallas(src, aux, row, lsrc, seg, w, emask_f32, *,
                     row_tile: int, program: VertexProgram, gather: str,
-                    interpret: bool):
+                    interpret: bool, frontier: bool = False):
     """Runs the fused tile program over all T tiles (one per grid step).
 
     Args (K-major, see the module docstring):
@@ -160,16 +167,19 @@ def csr_tile_pallas(src, aux, row, lsrc, seg, w, emask_f32, *,
         ``msg_gen`` gets zeros of the (ET, A) / (ET, K) shape instead.
       lsrc/seg (T, 1, ET) i32, w (T, 1, ET) f32, emask_f32 (T, 1, ET) f32.
       row_tile: RT, the row-block width of the outputs.
+      frontier: src holds K + 1 rows, the last the source's activity
+        (1.0 or 0.0), which the kernel multiplies into ``emask_f32``; a
+        frontier step gathers it with the state in one pass.
     Returns: partial (T, K, RT) f32, counts (T, 1, RT) i32 — per-tile row
     partials, the monoid identity / zero at rows no live edge reaches;
     split hub rows still need the cross-tile combine.
     """
     if gather not in ("take", "onehot"):
         raise ValueError(f"gather must be 'take' or 'onehot', got {gather!r}")
-    t, k, _ = src.shape
+    t, k = src.shape[0], program.state_width
     kern = functools.partial(_tile_kernel, program=program, gather=gather,
                              has_aux=aux is not None,
-                             has_row=row is not None)
+                             has_row=row is not None, frontier=frontier)
     out_shape = [jax.ShapeDtypeStruct((t, k, row_tile), jnp.float32),
                  jax.ShapeDtypeStruct((t, 1, row_tile), jnp.int32)]
     args = tuple(a for a in (src, aux, row, lsrc, seg, w, emask_f32)

@@ -47,13 +47,16 @@ def _one_column_at_least(aux):
     return jnp.zeros((aux.shape[0], 1), aux.dtype)
 
 
-def tile_gathers(program: VertexProgram) -> tuple[str, ...]:
+def tile_gathers(program: VertexProgram,
+                 frontier: bool = False) -> tuple[str, ...]:
     """The per-edge operands ``csr_aggregate``'s Pallas path gathers for
-    ``program``: its src state always, its dst state and its aux only
-    where ``msg_gen`` reads them (``VertexProgram.msg_gen_reads``).
+    ``program``: its src state always (``src+frontier`` where the
+    source's activity rides in the same rows), its dst state and its aux
+    only where ``msg_gen`` reads them (``VertexProgram.msg_gen_reads``).
     Each is gathered under its own scope inside ``plug.gather``."""
     reads = program.msg_gen_reads
-    return ("src",) + tuple(op for op in ("dst", "aux") if op in reads)
+    return (("src+frontier" if frontier else "src",)
+            + tuple(op for op in ("dst", "aux") if op in reads))
 
 
 # --------------------------------------------------------------------------
@@ -148,7 +151,7 @@ def _csr_tiles_xla(vsrc, vaux, rowst, lsrc, seg, w, emask, *,
 
 
 def csr_aggregate(state, aux, csr: dict, *, program: VertexProgram,
-                  num_vertices: int, config):
+                  num_vertices: int, config, active=None):
     """Fused gather + Gen + segmented Merge over CSR tiles → (N, K) agg.
 
     Args:
@@ -163,9 +166,14 @@ def csr_aggregate(state, aux, csr: dict, *, program: VertexProgram,
         global dst straight to (N, K) — XLA only; the tiled variants run
         the tile body (Pallas kernel or its XLA twin) and finish split
         hub rows with a cross-tile segmented combine.
+      active: optional (N,) bool frontier: only arcs out of an active
+        vertex send.  The Pallas tiled path gathers it as one more row
+        of the src state (one pass over the src ids); the other paths
+        mask ``emask & active[gsrc]`` first.
     Returns:
       agg (N, K) f32 — merged messages; vertices with no message read
       the monoid identity.  cnt (N,) i32 — messages per vertex.
+      live (T,) bool — the tiles with a live arc.
     Traceable (no jit of its own), so the same dispatch serves the
     per-shard daemon and the ``shard_map`` body of the sharded daemon.
     """
@@ -174,6 +182,14 @@ def csr_aggregate(state, aux, csr: dict, *, program: VertexProgram,
     n = num_vertices
     emask = csr["emask"]
     w = csr["w"].astype(jnp.float32)
+    fold = (active is not None and config.lowering == "pallas"
+            and config.merge != "flat")
+    if not fold:
+        with jax.named_scope("plug.gather"):
+            if active is not None:
+                with jax.named_scope("frontier"):
+                    emask = emask & active[csr["gsrc"]]
+            live = jnp.any(emask, axis=1)
     if config.merge == "flat":
         aux = _one_column_at_least(aux)
         gsrc = csr["gsrc"].reshape(-1)
@@ -201,11 +217,18 @@ def csr_aggregate(state, aux, csr: dict, *, program: VertexProgram,
                                 else (csr["svids"], csr["rows"]))
             # only what msg_gen reads is gathered: the kernel hands it
             # zeros in place of an absent aux or row operand
-            gathers = tile_gathers(program)
+            gathers = tile_gathers(program, frontier=fold)
             aux_e = row_e = None
             with jax.named_scope("plug.gather"):
-                with jax.named_scope("src"):
-                    src_e = _kmajor(state, src_ids)
+                with jax.named_scope(gathers[0]):
+                    table = state
+                    if fold:
+                        # the activity bit rides as column K of the
+                        # src rows; the kernel splits it off
+                        table = jnp.concatenate(
+                            [state, active[:, None].astype(state.dtype)],
+                            axis=1)
+                    src_e = _kmajor(table, src_ids)
                 if "dst" in gathers:
                     with jax.named_scope("dst"):
                         row_e = _kmajor(state, row_ids)
@@ -217,7 +240,12 @@ def csr_aggregate(state, aux, csr: dict, *, program: VertexProgram,
             partial, counts = csr_tile_pallas(
                 src_e, aux_e, row_e, *edges,
                 row_tile=csr["rows"].shape[1], program=program,
-                gather=config.gather, interpret=_default_interpret())
+                gather=config.gather, interpret=_default_interpret(),
+                frontier=fold)
+            if fold:
+                # exact: counts sums the kernel's live (edge, row) pairs
+                with jax.named_scope("plug.gather"):
+                    live = jnp.any(counts > 0, axis=(1, 2))
             with jax.named_scope("plug.combine.tiles"):
                 partial = jnp.swapaxes(partial, 1, 2)
         else:
@@ -234,7 +262,7 @@ def csr_aggregate(state, aux, csr: dict, *, program: VertexProgram,
             cnt = jax.ops.segment_sum(counts.reshape(-1), rows, n)
     with jax.named_scope("plug.combine.tiles"):
         agg = jnp.where((cnt > 0)[:, None], agg, monoid.identity)
-    return agg, cnt
+    return agg, cnt, live
 
 
 # --------------------------------------------------------------------------

@@ -241,7 +241,7 @@ class VectorizedDaemon:
             em = csr["emask"] & blk_mask[eblock]
             return kops.csr_aggregate(state, aux, dict(csr, emask=em),
                                       program=program, num_vertices=n,
-                                      config=cfg)
+                                      config=cfg)[:2]
 
         entry = {
             "csr": {k: jnp.asarray(v) for k, v in ts.arrays().items()},
@@ -307,7 +307,8 @@ class ShardedDaemon(VectorizedDaemon):
     (graph/compaction.py), autotunes the kernel config once on the
     largest shard, pads the tile sets to a common envelope and stacks
     them over the mesh axis next to the block tensors.  Frontier
-    skipping becomes a per-edge mask (``emask & active[gsrc]``) —
+    skipping becomes a per-edge mask (``emask & active[gsrc]``, on the
+    Pallas tile path the activity gathered with the src state) —
     trajectory-identical to block-granularity skipping for the
     idempotent monoids that drive frontiers — and ``blocks_run`` counts
     active *tiles*.  The same ``kernels.ops.csr_aggregate`` dispatch
@@ -711,39 +712,35 @@ class ShardedDaemon(VectorizedDaemon):
         n = self.n
         cfg = self._csr_config
         if cfg.lowering == "pallas" and cfg.merge != "flat":
-            self.edge_gathers = (("frontier",) * use_frontier
-                                 + kops.tile_gathers(program))
+            self.edge_gathers = kops.tile_gathers(program,
+                                                  frontier=use_frontier)
 
         def compute(state, aux, act, rows, seg, lsrc, svids, w, emask,
                     gsrc, gdst):
             # local slices (S/m, nt, …); state/aux replicated
             s_l, nt, et = lsrc.shape
-            with jax.named_scope("plug.gather"):
-                if use_frontier:
-                    # per-edge frontier filtering — trajectory-identical
-                    # to the block path's block-granularity skipping for
-                    # the idempotent monoids that drive frontiers
-                    with jax.named_scope("frontier"):
-                        em = emask & act[gsrc]
-                else:
-                    em = emask
-                tiles_run = jnp.any(em, axis=2).sum(axis=1).astype(
-                    jnp.int32)
             csr = {
                 "rows": rows.reshape(s_l * nt, -1),
                 "seg": seg.reshape(s_l * nt, et),
                 "lsrc": lsrc.reshape(s_l * nt, et),
                 "svids": svids.reshape(s_l * nt, -1),
                 "w": w.reshape(s_l * nt, et),
-                "emask": em.reshape(s_l * nt, et),
+                "emask": emask.reshape(s_l * nt, et),
                 "gsrc": gsrc.reshape(s_l * nt, et),
                 "gdst": gdst.reshape(s_l * nt, et),
             }
             # per-device partial combine happens inside csr_aggregate:
             # every tile's row partials (and the flat variant's direct
-            # segment reduce) land in one (N, K) aggregate per device
-            agg, cnt = kops.csr_aggregate(state, aux, csr, program=program,
-                                          num_vertices=n, config=cfg)
+            # segment reduce) land in one (N, K) aggregate per device.
+            # The per-edge frontier filter (act) is trajectory-identical
+            # to the block path's block-granularity skipping for the
+            # idempotent monoids that drive frontiers
+            agg, cnt, live = kops.csr_aggregate(
+                state, aux, csr, program=program, num_vertices=n,
+                config=cfg, active=act)
+            with jax.named_scope("plug.gather"):
+                tiles_run = live.reshape(s_l, nt).sum(axis=1).astype(
+                    jnp.int32)
             return agg[None], cnt[None], tiles_run
 
         return compute

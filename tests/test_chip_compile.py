@@ -93,6 +93,27 @@ def test_csr_tile_kernel_without_aux_and_row_compiles_for_v5e(
 
 
 @pytest.mark.parametrize("gather", ["take", "onehot"])
+@pytest.mark.parametrize("monoid", ["sum", "min"])
+def test_csr_tile_kernel_with_an_activity_row_compiles_for_v5e(
+        one_chip, monoid, gather):
+    """The kernel of a frontier step: the src operand carries the
+    source's activity as row K, split off inside the kernel."""
+    prog = _program(monoid)
+    width = ST if gather == "onehot" else ET
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (s((T, prog.state_width + 1, width)), s((T, 1, ET), jnp.int32),
+            s((T, 1, ET), jnp.int32), s((T, 1, ET)), s((T, 1, ET)))
+    text = _compiled_text(
+        lambda src, *edges: csr_tile_pallas(
+            src, None, None, *edges, row_tile=RT, program=prog,
+            gather=gather, interpret=False, frontier=True), args)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("gather", ["take", "onehot"])
 def test_csr_aggregate_compiles_for_v5e(one_chip, monkeypatch, gather):
     """The daemon's whole tile aggregate — K-major gathers, the kernel,
     the cross-tile combine — as the fused step traces it on a TPU."""
@@ -113,6 +134,33 @@ def test_csr_aggregate_compiles_for_v5e(one_chip, monkeypatch, gather):
     text = _compiled_text(
         lambda st, ax, c: ops.csr_aggregate(st, ax, c, program=prog,
                                             num_vertices=N, config=cfg),
+        args)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("gather", ["take", "onehot"])
+def test_csr_aggregate_with_a_frontier_compiles_for_v5e(one_chip,
+                                                        monkeypatch, gather):
+    """The frontier step's tile aggregate: the activity gathered with the
+    src state in one pass, the kernel's tile counts read back."""
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    prog = _program("min")
+    cfg = CSRConfig(edge_tile=ET, lowering="pallas", merge="onehot",
+                    gather=gather)
+
+    def s(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    csr = {"rows": s((T, RT)), "seg": s((T, ET)), "lsrc": s((T, ET)),
+           "svids": s((T, ST)), "w": s((T, ET), jnp.float32),
+           "emask": s((T, ET), jnp.bool_), "gsrc": s((T, ET)),
+           "gdst": s((T, ET))}
+    args = (s((N, prog.state_width), jnp.float32),
+            s((N, 1), jnp.float32), csr, s((N,), jnp.bool_))
+    text = _compiled_text(
+        lambda st, ax, c, act: ops.csr_aggregate(
+            st, ax, c, program=prog, num_vertices=N, config=cfg,
+            active=act),
         args)
     assert "tpu_custom_call" in text
 

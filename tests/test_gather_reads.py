@@ -93,8 +93,9 @@ def _aggregate(prog, graph, cfg, state, aux):
     ts = build_csr_tiles(graph.src, graph.dst, graph.weights, N_V,
                          edge_tile=cfg.edge_tile)
     csr = {k: jnp.asarray(v) for k, v in ts.arrays().items()}
-    agg, cnt = ops.csr_aggregate(jnp.asarray(state), jnp.asarray(aux), csr,
-                                 program=prog, num_vertices=N_V, config=cfg)
+    agg, cnt, _ = ops.csr_aggregate(
+        jnp.asarray(state), jnp.asarray(aux), csr, program=prog,
+        num_vertices=N_V, config=cfg)
     return np.asarray(agg), np.asarray(cnt)
 
 
@@ -138,6 +139,6 @@ def test_a_program_that_reads_matches_the_reference_run(graph, reads,
                          model="bsp", num_shards=1)
     res = mw.run()
     want, iters = plug.run_reference(graph, prog)
-    assert daemon.edge_gathers == ("frontier", "src", reads)
+    assert daemon.edge_gathers == ("src+frontier", reads)
     assert res.iterations == iters
     np.testing.assert_array_equal(np.asarray(res.state), want)

@@ -210,8 +210,9 @@ def _run_cfg(cfg, prog, state, aux, src, dst, w, active=None):
     csr = {k: jnp.asarray(v) for k, v in ts.arrays().items()}
     if active is not None:
         csr["emask"] = csr["emask"] & jnp.asarray(active)[csr["gsrc"]]
-    agg, cnt = ops.csr_aggregate(jnp.asarray(state), jnp.asarray(aux), csr,
-                                 program=prog, num_vertices=N_V, config=cfg)
+    agg, cnt, _ = ops.csr_aggregate(
+        jnp.asarray(state), jnp.asarray(aux), csr, program=prog,
+        num_vertices=N_V, config=cfg)
     return np.asarray(agg), np.asarray(cnt)
 
 
@@ -420,7 +421,7 @@ def test_pad_tileset_preserves_aggregate_bit_for_bit():
         outs = []
         for t in (ts, padded):
             csr = {k: jnp.asarray(v) for k, v in t.arrays().items()}
-            agg, cnt = ops.csr_aggregate(
+            agg, cnt, _ = ops.csr_aggregate(
                 jnp.asarray(state), jnp.asarray(aux), csr, program=prog,
                 num_vertices=N_V, config=cfg)
             outs.append((np.asarray(agg), np.asarray(cnt)))

@@ -28,7 +28,7 @@ SCOPES = ["plug.gather", "plug.combine.tiles", "plug.combine.devices",
 BUILD_SPANS = {"plug.build.partition", "plug.build.blocks",
                "plug.build.tiles", "plug.build.place"}
 SOURCES = [0, 1, 2, 3]
-EDGE_GATHERS = ["frontier", "src", "dst", "aux"]  # scopes in plug.gather
+EDGE_GATHERS = ["frontier", "src", "src+frontier", "dst", "aux"]  # in plug.gather
 
 
 def _middleware(graph, program):
@@ -69,10 +69,11 @@ def _gather_scopes(text) -> set:
 
 def test_sssp_step_gathers_no_aux_and_no_dst_state(sssp_step):
     """SSSP's messages read the src state and the weight: the step
-    gathers the frontier and the src state per edge, nothing more."""
+    gathers the src state per edge with the frontier bit in the same
+    rows, nothing more."""
     daemon, text = sssp_step
-    assert _gather_scopes(text) == {"frontier", "src"}
-    assert set(daemon.edge_gathers) == {"frontier", "src"}
+    assert _gather_scopes(text) == {"src+frontier"}
+    assert set(daemon.edge_gathers) == {"src+frontier"}
 
 
 def test_a_step_reading_dst_state_gathers_it(small):
@@ -81,8 +82,8 @@ def test_a_step_reading_dst_state_gathers_it(small):
         msg_gen=lambda s, d, w, a: jnp.minimum(s + w, d))
     mw = _middleware(small, prog)
     text = DriveLoop(mw).lower().as_text(debug_info=True)
-    assert _gather_scopes(text) == {"frontier", "src", "dst"}
-    assert mw.daemon.edge_gathers == ("frontier", "src", "dst")
+    assert _gather_scopes(text) == {"src+frontier", "dst"}
+    assert mw.daemon.edge_gathers == ("src+frontier", "dst")
 
 
 def _self_seconds(build_spans) -> dict:
