@@ -17,12 +17,10 @@ device-resident path is directly measurable against Fig. 8's baselines.
 
 A third table sweeps the fused loop itself: ``daemon="sharded"`` ×
 ``kernel={reference, pallas}`` (the shard_map body: the dense gather/
-scatter reference vs the autotuned CSR tile path — whose autotuner picks
-the fused Pallas lowering on TPU and legitimately falls back to its XLA
-twin on CPU, where Pallas only interprets) × ``model={bsp, async}``
-(the barriered fused step vs the priority/staleness async step), per-
-iteration steady-state times, plus the pallas/reference ratio per model
-and the autotune sweep tables that produced the CSR configs.
+scatter reference vs the fused CSR tile path, whose Pallas kernel only
+interprets on CPU) × ``model={bsp, async}`` (the barriered fused step
+vs the priority/staleness async step), per-iteration steady-state
+times, plus the pallas/reference ratio per model.
 
 A fault-recovery row (DESIGN.md §4.4) kills a device mid-run via
 ``dist.fault.FailureSchedule`` and records what elastic recovery costs:
@@ -709,11 +707,6 @@ def run(small: bool = True, quick: bool = False,
         iters=iters["pagerank"] + 2)
     out["dynamic"] = _dynamic_table(quick)
     out["async_skew"] = _async_skew_table(quick)
-    # the autotune sweeps the pallas cells triggered above: chosen config
-    # + the full per-config timing table, per (shape, monoid) signature —
-    # auditable from BENCH_plug.json, not just the winning label
-    from repro.kernels.autotune import CACHE
-    out["autotune"] = CACHE.report()
     import jax
     out["_meta"] = {"api": "repro.plug.Middleware", "quick": quick,
                     "graph": {"num_vertices": g.num_vertices,
@@ -801,7 +794,7 @@ def main():
           f"wire saved {ct['wire_bytes_saved']}/{ct['wire_bytes_baseline']}B")
     for alg, r in results.items():
         if not (isinstance(r, dict) and "naive" in r):
-            continue  # _meta / autotune
+            continue  # _meta
         print(f"{alg:12s} naive={r['naive']:.2f}s blocked={r['blocked']:.2f}s "
               f"vectorized={r['vectorized']:.3f}s "
               f"accel={r['speedup_vectorized']:.1f}x")

@@ -51,7 +51,7 @@ def main():
 def _summarize(name, result):
     if name == "bench_accel":
         for alg, r in result.items():
-            # skip the non-algorithm entries (_meta, fault_recovery, autotune)
+            # skip the non-algorithm entries (_meta, fault_recovery)
             if isinstance(r, dict) and "speedup_vectorized" in r:
                 print(f"    {alg}: {r['speedup_vectorized']:.1f}x accel")
                 # direct indexing: a dropped kernel×model cell must

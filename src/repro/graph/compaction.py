@@ -10,8 +10,7 @@ the sorted edge list into fixed-size *edge tiles*.  Each tile carries
   * a compact **src block** — the distinct source vertices the tile
     reads (``svids``), addressed through tile-local ``lsrc`` indices;
   * the edge data itself (``w``, ``emask``) plus the global endpoints
-    (``gsrc`` for frontier filtering, ``gdst`` for the flat fused
-    combine).
+    (``gsrc`` and ``gdst``, through which the daemon gathers per edge).
 
 Degree bucketing decides how rows map to tiles:
 
@@ -21,7 +20,7 @@ Degree bucketing decides how rows map to tiles:
   * **hub rows** (in-degree > ``hub_threshold``) are split across as
     many dedicated tiles as they need; the per-tile partials of a split
     row are finished by the cross-tile segmented combine
-    (``kernels.ops.csr_aggregate``), which every variant runs anyway.
+    (``kernels.ops.csr_aggregate``), which every step runs anyway.
 
 Tile shapes are uniform (ET edges, RT ≤ rows, ST ≤ srcs, both rounded to
 multiples of 8 for TPU sublane alignment), so ONE compiled tile program
@@ -56,7 +55,7 @@ class CSRTileSet:
     w      (nt, ET, 1) f32    edge weights (1.0 if unweighted)
     emask  (nt, ET)    bool   valid edge slots
     gsrc   (nt, ET)    int32  global src ids (frontier filtering)
-    gdst   (nt, ET)    int32  global dst ids (flat fused combine)
+    gdst   (nt, ET)    int32  global dst ids (per-edge dst gather)
     eblock (nt, ET)    int32  owning edge-block id (block-granularity
                               frontier selection; -1 when not built
                               from a BlockSet)

@@ -1,187 +1,44 @@
-"""Autotuning for the fused CSR aggregation kernel (DESIGN.md §3.1).
+"""The CSR tile path's configuration (DESIGN.md §3.1).
 
-The CSR daemon program has real implementation freedom: edge-tile size,
-gather strategy (vector ``take`` vs one-hot MXU matmul), merge strategy
-(flat global sorted-segment reduce vs per-tile sorted segments vs one-hot
-matmul), and lowering (Pallas kernel vs its XLA twin — the same per-tile
-math batched over tiles).  The best point depends on backend, graph shape
-and monoid, so the daemons sweep once per (backend, shape, program)
-signature and cache the winner.  The sweep table (per-config timings) is
-exported into BENCH_plug.json by benchmarks/bench_accel.py so the choice
-is auditable.
-
-Every candidate computes the identical aggregate — min/max/or variants
-bit-identically (selection monoids), sum up to merge order — so tuning is
-purely a performance decision; tests/test_kernels.py asserts the
-equivalence across the whole space.
+The daemon runs one CSR tile path on every backend: per-edge ``take``
+gathers in XLA, then the fused Pallas tile kernel (Gen + one-hot/masked
+Merge), then the cross-tile combine.  Its one tuning left is the edge
+tile (and the hub threshold that degree bucketing derives from it).
 """
 from __future__ import annotations
 
 import dataclasses
-import time
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from repro.core.template import VertexProgram
-from repro.graph.compaction import build_csr_tiles
-from repro.kernels import ops
 
 
 @dataclasses.dataclass(frozen=True)
 class CSRConfig:
-    """One point of the CSR-kernel tuning space.
+    """The CSR tile path's parameters.
 
     Attributes:
       edge_tile: edges per tile (ET); also the degree-bucketing hub
         threshold unless ``hub_threshold`` overrides it.
-      lowering: "xla" (batched twin) or "pallas" (the fused kernel;
-        interpret mode off-TPU).  Ignored when merge == "flat".
-      merge: "flat" (single global sorted-segment reduce to (N, K) —
-        fewest ops, XLA only), "sorted" (per-tile sorted segments), or
-        "onehot" (MXU matmul merge).
-      gather: "take" (vector gather) or "onehot" (MXU matmul gather);
-        ignored when merge == "flat".
+      hub_threshold: in-degree above which a row streams across tiles;
+        None means ``edge_tile``.
+      lowering, merge, gather: the path's fixed names ("pallas",
+        "onehot", "take"), kept so a config that spells them still
+        reads; any other value raises.
     """
 
     edge_tile: int = 512
-    lowering: str = "xla"
-    merge: str = "flat"
+    lowering: str = "pallas"
+    merge: str = "onehot"
     gather: str = "take"
     hub_threshold: int | None = None
+
+    def __post_init__(self):
+        for field, want in (("lowering", "pallas"), ("merge", "onehot"),
+                            ("gather", "take")):
+            got = getattr(self, field)
+            if got != want:
+                raise ValueError(
+                    f"CSRConfig.{field}={got!r}: the CSR tile path is "
+                    f"pallas/onehot/take, {field} must be {want!r}")
 
     @property
     def label(self) -> str:
         return f"{self.lowering}/{self.merge}/{self.gather}/et{self.edge_tile}"
-
-
-#: Default sweep: the flat-merge family at three tile sizes (tile size
-#: changes only padding there, but padding is the cost that matters at
-#: small scale), the tiled XLA twins, and the Pallas kernel proper in
-#: both gather modes.  On TPU the Pallas rows compile natively; on CPU
-#: they run in interpret mode and the sweep legitimately selects an XLA
-#: point — that asymmetry is exactly what the recorded table documents.
-DEFAULT_SPACE: tuple[CSRConfig, ...] = (
-    CSRConfig(edge_tile=256, merge="flat"),
-    CSRConfig(edge_tile=512, merge="flat"),
-    CSRConfig(edge_tile=1024, merge="flat"),
-    CSRConfig(edge_tile=512, lowering="xla", merge="sorted", gather="take"),
-    CSRConfig(edge_tile=512, lowering="xla", merge="onehot", gather="onehot"),
-    CSRConfig(edge_tile=512, lowering="pallas", merge="onehot",
-              gather="onehot"),
-    CSRConfig(edge_tile=256, lowering="pallas", merge="onehot",
-              gather="take"),
-)
-
-
-class AutotuneCache:
-    """Process-wide memo of sweep results keyed by problem signature.
-
-    ``sweeps`` counts actual timing sweeps run; ``hits`` counts lookups
-    answered from the memo — the cache-regression test pins a second
-    identically-shaped bind to hits, not sweeps.
-    """
-
-    def __init__(self):
-        self._entries: dict[tuple, dict] = {}
-        self.sweeps = 0
-        self.hits = 0
-
-    def clear(self) -> None:
-        self._entries.clear()
-        self.sweeps = 0
-        self.hits = 0
-
-    def lookup(self, key):
-        entry = self._entries.get(key)
-        if entry is not None:
-            self.hits += 1
-        return entry
-
-    def store(self, key, entry) -> None:
-        self._entries[key] = entry
-        self.sweeps += 1
-
-    def report(self) -> dict:
-        """JSON-ready view for BENCH_plug.json's ``autotune`` section."""
-        return {
-            "sweeps": self.sweeps,
-            "hits": self.hits,
-            "entries": [
-                {
-                    "backend": k[0],
-                    "num_vertices": k[1],
-                    "num_edges": k[2],
-                    "state_width": k[3],
-                    "aux_width": k[4],
-                    "monoid": k[5],
-                    "chosen": e["config"].label,
-                    "table": e["table"],
-                }
-                for k, e in sorted(self._entries.items(),
-                                   key=lambda kv: repr(kv[0]))
-            ],
-        }
-
-
-#: The global cache the daemons share.
-CACHE = AutotuneCache()
-
-
-def signature(num_vertices: int, num_edges: int, program: VertexProgram,
-              space: tuple[CSRConfig, ...]) -> tuple:
-    return (jax.default_backend(), int(num_vertices), int(num_edges),
-            program.state_width, program.aux_width, program.monoid.name,
-            tuple(c.label for c in space))
-
-
-def _time_config(src, dst, weights, num_vertices, program, config, *,
-                 repeats: int) -> float:
-    ts = build_csr_tiles(src, dst, weights, num_vertices,
-                         edge_tile=config.edge_tile,
-                         hub_threshold=config.hub_threshold)
-    csr = {k: jnp.asarray(v) for k, v in ts.arrays().items()}
-    state = jnp.ones((num_vertices, program.state_width), jnp.float32)
-    aux = jnp.ones((num_vertices, max(program.aux_width, 1)), jnp.float32)
-
-    @jax.jit
-    def run(state, aux, csr):
-        return ops.csr_aggregate(state, aux, csr, program=program,
-                                 num_vertices=num_vertices,
-                                 config=config)[:2]
-
-    agg, cnt = run(state, aux, csr)  # compile + warm up
-    jax.block_until_ready((agg, cnt))
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        jax.block_until_ready(run(state, aux, csr))
-        best = min(best, time.perf_counter() - t0)
-    return best
-
-
-def autotune_csr(src: np.ndarray, dst: np.ndarray,
-                 weights: np.ndarray | None, num_vertices: int,
-                 program: VertexProgram, *,
-                 space: tuple[CSRConfig, ...] | None = None,
-                 cache: AutotuneCache | None = None,
-                 repeats: int = 3) -> CSRConfig:
-    """Sweeps the config space on this shard's edge list, returns the
-    fastest config.  Results are memoized in ``cache`` (default: the
-    global CACHE) keyed by (backend, |V|, |E|, K, A, monoid, space), so
-    re-binding an identically-shaped problem is a pure lookup."""
-    space = DEFAULT_SPACE if space is None else tuple(space)
-    cache = CACHE if cache is None else cache
-    key = signature(num_vertices, len(src), program, space)
-    entry = cache.lookup(key)
-    if entry is None:
-        table = {}
-        for config in space:
-            table[config.label] = _time_config(
-                src, dst, weights, num_vertices, program, config,
-                repeats=repeats)
-        chosen = min(space, key=lambda c: table[c.label])
-        entry = {"config": chosen, "table": table}
-        cache.store(key, entry)
-    return entry["config"]

@@ -6,12 +6,13 @@ in ``interpret=True`` mode, which checks correctness only.  Models and
 benchmarks can also select the pure-jnp reference implementations
 (``impl="reference"``), which is what the 512-device dry-run lowers.
 
-On one TPU v5e chip the graph daemon's CSR tile kernel
-(``csr_tile_pallas`` with ``gather="take"``, one-hot merge, 512-edge
-tiles) has run inside the sharded fused drive loop at Graph500 SCALE 22,
-and on a four-chip v5e mesh at SCALE 18 (``chip_smoke.py``).
-``gather="onehot"`` and ``edge_block_aggregate``
-have only been compiled for a described v5e
+The graph daemon has one CSR tile path on every backend
+(``csr_aggregate``): ``take`` gathers per edge, the ``csr_tile_pallas``
+kernel with its one-hot merge, the cross-tile combine.  On one TPU v5e
+chip it has run inside the sharded fused drive loop at Graph500 SCALE 22
+with 512-edge tiles, and on a four-chip v5e mesh at SCALE 18
+(``chip_smoke.py``).  The kernel's ``gather="onehot"`` mode, used only
+by ``edge_block_aggregate``, has only been compiled for a described v5e
 (``tests/test_chip_compile.py``); the flash-attention and SSD kernels
 have only run in interpret mode.
 """
@@ -49,7 +50,7 @@ def _one_column_at_least(aux):
 
 def tile_gathers(program: VertexProgram,
                  frontier: bool = False) -> tuple[str, ...]:
-    """The per-edge operands ``csr_aggregate``'s Pallas path gathers for
+    """The per-edge operands ``csr_aggregate`` gathers for
     ``program``: its src state always (``src+frontier`` where the
     source's activity rides in the same rows), its dst state and its aux
     only where ``msg_gen`` reads them (``VertexProgram.msg_gen_reads``).
@@ -88,88 +89,25 @@ def edge_block_aggregate(state, aux, vids, lsrc, ldst, w, emask, *,
 # --------------------------------------------------------------------------
 # CSR tile aggregation (the fused daemon program, DESIGN.md §3.1)
 # --------------------------------------------------------------------------
-def _csr_tiles_xla(vsrc, vaux, rowst, lsrc, seg, w, emask, *,
-                   program: VertexProgram, merge: str, gather: str):
-    """XLA twin of the Pallas CSR tile kernel: identical per-tile math,
-    batched over the tile axis — the lowering the autotuner selects on
-    backends where interpret-mode Pallas would pay per-op dispatch."""
-    monoid = program.monoid
-    k = program.state_width
-    t, st, _ = vsrc.shape
-    rt = rowst.shape[1]
-    et = lsrc.shape[1]
-    if gather == "onehot":
-        soh = (lsrc[..., None]
-               == jnp.arange(st, dtype=lsrc.dtype)[None, None, :]
-               ).astype(jnp.float32)
-        roh_f = (seg[..., None]
-                 == jnp.arange(rt, dtype=seg.dtype)[None, None, :]
-                 ).astype(jnp.float32)
-        s = jnp.einsum("tes,tsk->tek", soh, vsrc)
-        sa = jnp.einsum("tes,tsa->tea", soh, vaux)
-        d = jnp.einsum("ter,trk->tek", roh_f, rowst)
-    else:
-        s = jnp.take_along_axis(vsrc, lsrc[..., None], axis=1)
-        sa = jnp.take_along_axis(vaux, lsrc[..., None], axis=1)
-        d = jnp.take_along_axis(rowst, seg[..., None], axis=1)
-    msgs = program.msg_gen(
-        s.reshape(t * et, k), d.reshape(t * et, k),
-        w.reshape(t * et, 1), sa.reshape(t * et, -1)).reshape(t, et, k)
-    msgs = jnp.where(emask[..., None], msgs, monoid.identity)
-    if merge == "sorted":
-        # seg is sorted tile-local — a single flat sorted-segment reduce
-        segg = (seg + jnp.arange(t, dtype=seg.dtype)[:, None] * rt
-                ).reshape(-1)
-        partial = monoid.segment_reduce(msgs.reshape(t * et, k), segg,
-                                        t * rt)
-        counts = jax.ops.segment_sum(
-            emask.reshape(-1).astype(jnp.int32), segg, t * rt)
-        partial = jnp.where((counts > 0)[:, None], partial,
-                            monoid.identity)
-        return partial.reshape(t, rt, k), counts.reshape(t, rt)
-    # merge == "onehot": the MXU form, kept bit-identical to the kernel
-    roh = (seg[..., None] == jnp.arange(rt, dtype=seg.dtype)[None, None, :])
-    live = roh & emask[..., None]  # (T, ET, RT)
-    if monoid.name == "sum":
-        partial = jnp.einsum("ter,tek->trk", live.astype(jnp.float32),
-                             msgs)
-    elif monoid.name in ("min", "max", "or"):
-        sel = jnp.swapaxes(live, 1, 2)  # (T, RT, ET)
-        cols = []
-        for i in range(k):  # K is small & static
-            mat = jnp.where(sel, msgs[..., i][:, None, :], monoid.identity)
-            red = (jnp.min(mat, axis=2) if monoid.name == "min"
-                   else jnp.max(mat, axis=2))
-            cols.append(red)
-        partial = jnp.stack(cols, axis=2)
-    else:
-        raise ValueError(
-            f"monoid {monoid.name!r} has no CSR merge rule; known: "
-            "['max', 'min', 'or', 'sum']")
-    counts = live.sum(axis=1).astype(jnp.int32)
-    return partial, counts
-
-
 def csr_aggregate(state, aux, csr: dict, *, program: VertexProgram,
-                  num_vertices: int, config, active=None):
+                  num_vertices: int, active=None):
     """Fused gather + Gen + segmented Merge over CSR tiles → (N, K) agg.
+
+    The one CSR tile path: ``take`` gathers per edge in XLA (only the
+    operands ``msg_gen`` reads, :func:`tile_gathers`), the fused tile
+    kernel (``csr_tile_pallas``: Gen + one-hot/masked Merge per tile),
+    then the cross-tile segmented combine that finishes split hub rows.
 
     Args:
       state (N, K) f32, aux (N, A) f32 — the shard vertex table.
       csr: dict of per-tile arrays with leading tile axis T (the
         ``CSRTileSet.arrays()`` layout): rows (T, RT), seg/lsrc/gsrc/gdst
-        (T, ET), svids (T, ST), w (T, ET), emask (T, ET) bool.
+        (T, ET), w (T, ET), emask (T, ET) bool.
         ``emask`` may already carry per-edge frontier filtering.
-      config: a ``kernels.autotune.CSRConfig`` (or any object with
-        edge_tile/lowering/merge/gather attributes).  ``merge="flat"``
-        skips per-tile partials entirely: one sorted-segment reduce by
-        global dst straight to (N, K) — XLA only; the tiled variants run
-        the tile body (Pallas kernel or its XLA twin) and finish split
-        hub rows with a cross-tile segmented combine.
       active: optional (N,) bool frontier: only arcs out of an active
-        vertex send.  The Pallas tiled path gathers it as one more row
-        of the src state (one pass over the src ids); the other paths
-        mask ``emask & active[gsrc]`` first.
+        vertex send.  It is gathered as one more row of the src state
+        (one pass over the src ids) and the kernel folds it into its
+        edge mask.
     Returns:
       agg (N, K) f32 — merged messages; vertices with no message read
       the monoid identity.  cnt (N,) i32 — messages per vertex.
@@ -180,87 +118,48 @@ def csr_aggregate(state, aux, csr: dict, *, program: VertexProgram,
     monoid = program.monoid
     k = program.state_width
     n = num_vertices
-    emask = csr["emask"]
+    fold = active is not None
+    t, et = csr["lsrc"].shape
     w = csr["w"].astype(jnp.float32)
-    fold = (active is not None and config.lowering == "pallas"
-            and config.merge != "flat")
-    if not fold:
-        with jax.named_scope("plug.gather"):
-            if active is not None:
-                with jax.named_scope("frontier"):
-                    emask = emask & active[csr["gsrc"]]
-            live = jnp.any(emask, axis=1)
-    if config.merge == "flat":
-        aux = _one_column_at_least(aux)
-        gsrc = csr["gsrc"].reshape(-1)
-        gdst = csr["gdst"].reshape(-1)
-        emf = emask.reshape(-1)
-        msgs = program.msg_gen(state[gsrc], state[gdst],
-                               w.reshape(-1, 1), aux[gsrc])
-        msgs = jnp.where(emf[:, None], msgs, monoid.identity)
-        # dead/padded slots carry dst 0: they merge an identity into
-        # vertex 0 — a no-op, same convention as the block layout
-        agg = monoid.segment_reduce(msgs, gdst, n)
-        cnt = jax.ops.segment_sum(emf.astype(jnp.int32), gdst, n)
-    else:
-        if config.lowering == "pallas":
-            t, et = csr["lsrc"].shape
 
-            def edge_rows(x):
-                return x.reshape(t, 1, et)
+    def edge_rows(x):
+        return x.reshape(t, 1, et)
 
-            # "take" gathers per edge from the global ids (same values as
-            # svids[lsrc] / rows[seg] on live slots); "onehot" hands the
-            # kernel the compact blocks
-            src_ids, row_ids = ((csr["gsrc"], csr["gdst"])
-                                if config.gather == "take"
-                                else (csr["svids"], csr["rows"]))
-            # only what msg_gen reads is gathered: the kernel hands it
-            # zeros in place of an absent aux or row operand
-            gathers = tile_gathers(program, frontier=fold)
-            aux_e = row_e = None
-            with jax.named_scope("plug.gather"):
-                with jax.named_scope(gathers[0]):
-                    table = state
-                    if fold:
-                        # the activity bit rides as column K of the
-                        # src rows; the kernel splits it off
-                        table = jnp.concatenate(
-                            [state, active[:, None].astype(state.dtype)],
-                            axis=1)
-                    src_e = _kmajor(table, src_ids)
-                if "dst" in gathers:
-                    with jax.named_scope("dst"):
-                        row_e = _kmajor(state, row_ids)
-                if "aux" in gathers:
-                    with jax.named_scope("aux"):
-                        aux_e = _kmajor(_one_column_at_least(aux), src_ids)
-                edges = (edge_rows(csr["lsrc"]), edge_rows(csr["seg"]),
-                         edge_rows(w), edge_rows(emask.astype(jnp.float32)))
-            partial, counts = csr_tile_pallas(
-                src_e, aux_e, row_e, *edges,
-                row_tile=csr["rows"].shape[1], program=program,
-                gather=config.gather, interpret=_default_interpret(),
-                frontier=fold)
+    # only what msg_gen reads is gathered: the kernel hands it zeros in
+    # place of an absent aux or row operand
+    gathers = tile_gathers(program, frontier=fold)
+    aux_e = row_e = None
+    with jax.named_scope("plug.gather"):
+        with jax.named_scope(gathers[0]):
+            table = state
             if fold:
-                # exact: counts sums the kernel's live (edge, row) pairs
-                with jax.named_scope("plug.gather"):
-                    live = jnp.any(counts > 0, axis=(1, 2))
-            with jax.named_scope("plug.combine.tiles"):
-                partial = jnp.swapaxes(partial, 1, 2)
-        else:
-            aux = _one_column_at_least(aux)
-            partial, counts = _csr_tiles_xla(
-                state[csr["svids"]], aux[csr["svids"]], state[csr["rows"]],
-                csr["lsrc"], csr["seg"], w, emask, program=program,
-                merge=config.merge, gather=config.gather)
-        with jax.named_scope("plug.combine.tiles"):
-            # cross-tile combine: finishes split hub rows and folds every
-            # tile's row partials into the shard aggregate
-            rows = csr["rows"].reshape(-1)
-            agg = monoid.segment_reduce(partial.reshape(-1, k), rows, n)
-            cnt = jax.ops.segment_sum(counts.reshape(-1), rows, n)
+                # the activity bit rides as column K of the src rows;
+                # the kernel splits it off
+                table = jnp.concatenate(
+                    [state, active[:, None].astype(state.dtype)], axis=1)
+            src_e = _kmajor(table, csr["gsrc"])
+        if "dst" in gathers:
+            with jax.named_scope("dst"):
+                row_e = _kmajor(state, csr["gdst"])
+        if "aux" in gathers:
+            with jax.named_scope("aux"):
+                aux_e = _kmajor(_one_column_at_least(aux), csr["gsrc"])
+        edges = (edge_rows(csr["lsrc"]), edge_rows(csr["seg"]),
+                 edge_rows(w), edge_rows(csr["emask"].astype(jnp.float32)))
+    partial, counts = csr_tile_pallas(
+        src_e, aux_e, row_e, *edges, row_tile=csr["rows"].shape[1],
+        program=program, gather="take", interpret=_default_interpret(),
+        frontier=fold)
+    with jax.named_scope("plug.gather"):
+        # exact: counts sums the kernel's live (edge, row) pairs
+        live = jnp.any(counts > 0, axis=(1, 2))
     with jax.named_scope("plug.combine.tiles"):
+        # cross-tile combine: finishes split hub rows and folds every
+        # tile's row partials into the shard aggregate
+        partial = jnp.swapaxes(partial, 1, 2)
+        rows = csr["rows"].reshape(-1)
+        agg = monoid.segment_reduce(partial.reshape(-1, k), rows, n)
+        cnt = jax.ops.segment_sum(counts.reshape(-1), rows, n)
         agg = jnp.where((cnt > 0)[:, None], agg, monoid.identity)
     return agg, cnt, live
 

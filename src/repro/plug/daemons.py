@@ -9,10 +9,10 @@ middleware cannot tell them apart:
   power of two to bound recompiles.  ``kernel="reference"`` lowers pure
   jnp; ``kernel="pallas"`` runs the fused CSR tile program instead
   (graph/compaction.py + kernels.ops.csr_aggregate): the blockset is
-  compacted once into dst-grouped tiles, the autotuner picks the
-  lowering/merge/gather point (kernels/autotune.py), and block-granularity
-  frontier selection maps onto the fixed tile layout as a per-edge mask —
-  no padded-active-set buckets, one compiled shape for the whole run.
+  compacted once into dst-grouped tiles of ``CSRConfig.edge_tile`` edges
+  (kernels/autotune.py), and block-granularity frontier selection maps
+  onto the fixed tile layout as a per-edge mask — no padded-active-set
+  buckets, one compiled shape for the whole run.
 * ``BlockedDaemon``     — the paper's 5-step flow collapsed to 3:
   sequential Download → Compute → Upload per block.
 * ``PipelinedDaemon``   — the 3-thread pipeline shuffle with rotating
@@ -44,6 +44,7 @@ from repro.core import pipeline as pl
 from repro.core.blocks import BlockSet
 from repro.core.pow2 import pad_pow2
 from repro.core.template import VertexProgram
+from repro.kernels.autotune import CSRConfig
 from repro.plug.spans import build_span
 
 KERNELS = ("reference", "pallas")
@@ -174,13 +175,6 @@ def _stacked_field(st: dict, name: str):
     return st.get(name)
 
 
-def _live_edges(bs: BlockSet):
-    """Extracts the real (unpadded) edges of a BlockSet as flat arrays."""
-    live = bs.emask.reshape(-1)
-    return (bs.gsrc.reshape(-1)[live], bs.gdst.reshape(-1)[live],
-            bs.weights.reshape(-1)[live])
-
-
 class VectorizedDaemon:
     """All active blocks in one fused jit call — the optimized path."""
 
@@ -190,11 +184,10 @@ class VectorizedDaemon:
         if kernel not in KERNELS:
             raise ValueError(f"kernel must be one of {KERNELS}, got {kernel!r}")
         self.kernel = kernel
-        self.csr_config = csr_config  # user override; None → autotune
         self.program = None
         self.block_fn = None
         self._combine_fn = None
-        self._csr_config = None  # resolved per binding
+        self._csr_config = csr_config or CSRConfig()
         self._csr_cache: dict = {}  # id(blockset) -> compiled CSR entry
 
     def bind(self, program: VertexProgram, num_vertices: int):
@@ -202,23 +195,9 @@ class VectorizedDaemon:
         self.n = num_vertices
         self.block_fn = make_block_fn(program, kernel=self.kernel)
         self._combine_fn = make_combine_fn(program, num_vertices)
-        # a rebind invalidates the compacted tiles and the tuned config
-        # (the monoid may have changed); an explicit csr_config survives
-        self._csr_config = None
+        # a rebind invalidates the compacted tiles
         self._csr_cache = {}
         return self
-
-    def _resolve_csr_config(self, src, dst, w):
-        """Autotunes once per binding (deferred to first run so unknown
-        monoids raise at run time, matching the block-path contract);
-        shards bound after the first reuse the chosen config."""
-        if self._csr_config is None:
-            from repro.kernels import autotune as at
-
-            self._csr_config = (
-                self.csr_config if self.csr_config is not None
-                else at.autotune_csr(src, dst, w, self.n, self.program))
-        return self._csr_config
 
     def _csr_entry(self, blockset: BlockSet):
         key = id(blockset)
@@ -228,7 +207,7 @@ class VectorizedDaemon:
         from repro.graph.compaction import tiles_from_blockset
         from repro.kernels import ops as kops
 
-        cfg = self._resolve_csr_config(*_live_edges(blockset))
+        cfg = self._csr_config
         ts = tiles_from_blockset(blockset, self.n, edge_tile=cfg.edge_tile,
                                  hub_threshold=cfg.hub_threshold)
         program, n = self.program, self.n
@@ -240,8 +219,7 @@ class VectorizedDaemon:
             # but their base emask is already False
             em = csr["emask"] & blk_mask[eblock]
             return kops.csr_aggregate(state, aux, dict(csr, emask=em),
-                                      program=program, num_vertices=n,
-                                      config=cfg)[:2]
+                                      program=program, num_vertices=n)[:2]
 
         entry = {
             "csr": {k: jnp.asarray(v) for k, v in ts.arrays().items()},
@@ -304,14 +282,13 @@ class ShardedDaemon(VectorizedDaemon):
     ``kernel="pallas"`` runs the fused CSR tile program inside the
     ``shard_map`` body instead of the block program: ``bind_shards``
     compacts every shard's blockset into dst-grouped tiles
-    (graph/compaction.py), autotunes the kernel config once on the
-    largest shard, pads the tile sets to a common envelope and stacks
-    them over the mesh axis next to the block tensors.  Frontier
-    skipping becomes a per-edge mask (``emask & active[gsrc]``, on the
-    Pallas tile path the activity gathered with the src state) —
-    trajectory-identical to block-granularity skipping for the
-    idempotent monoids that drive frontiers — and ``blocks_run`` counts
-    active *tiles*.  The same ``kernels.ops.csr_aggregate`` dispatch
+    (graph/compaction.py) with the daemon's ``CSRConfig``, pads the tile
+    sets to a common envelope and stacks them over the mesh axis next to
+    the block tensors.  Frontier skipping becomes a per-edge mask (the
+    source's activity gathered with its state, folded into the kernel's
+    edge mask) — trajectory-identical to block-granularity skipping for
+    the idempotent monoids that drive frontiers — and ``blocks_run``
+    counts active *tiles*.  The same ``kernels.ops.csr_aggregate`` dispatch
     serves the per-shard ``VectorizedDaemon``, so sharded and
     vectorized stay bit-identical per kernel for idempotent monoids.
     """
@@ -345,8 +322,8 @@ class ShardedDaemon(VectorizedDaemon):
         self._tile_cache: dict = {}
         self.tiles_recut = 0
         self.tilesets_reused = 0
-        # the per-edge gathers of the last CSR step built with the Pallas
-        # tile kernel, as named by the device scopes inside plug.gather
+        # the per-edge gathers of the last CSR step built, as named by
+        # the device scopes inside plug.gather
         self.edge_gathers: tuple[str, ...] = ()
         # masked execution (MaskCapableDaemon): vertex-level priority
         # buckets + Gen-invocation instrumentation.  ``instrument`` adds
@@ -503,10 +480,8 @@ class ShardedDaemon(VectorizedDaemon):
         """Compacts every shard's blockset into CSR tiles, pads them to a
         common (nt, RT, ST) envelope and places the stacked arrays.
 
-        The kernel config is autotuned once, on the largest shard (the
-        shard that dominates the step), and pinned on the daemon — a
-        mid-run ``remesh`` re-stacks with the already-chosen config, so
-        checkpoint-free migration never pays a re-sweep.
+        Every shard is cut with the daemon's ``CSRConfig``, so a mid-run
+        ``remesh`` re-stacks with the same tile size.
 
         Compaction is cached per BlockSet object: a re-bind that keeps
         some blocksets (migration reorder; mutation where clean shards'
@@ -518,8 +493,7 @@ class ShardedDaemon(VectorizedDaemon):
         """
         from repro.graph.compaction import pad_tileset, tiles_from_blockset
 
-        big = max(blocksets, key=lambda bs: int(bs.emask.sum()))
-        cfg = self._resolve_csr_config(*_live_edges(big))
+        cfg = self._csr_config
         tiles = []
         for bs in blocksets:
             hit = self._tile_cache.get(id(bs))
@@ -710,10 +684,7 @@ class ShardedDaemon(VectorizedDaemon):
 
         program = self.program
         n = self.n
-        cfg = self._csr_config
-        if cfg.lowering == "pallas" and cfg.merge != "flat":
-            self.edge_gathers = kops.tile_gathers(program,
-                                                  frontier=use_frontier)
+        self.edge_gathers = kops.tile_gathers(program, frontier=use_frontier)
 
         def compute(state, aux, act, rows, seg, lsrc, svids, w, emask,
                     gsrc, gdst):
@@ -723,21 +694,19 @@ class ShardedDaemon(VectorizedDaemon):
                 "rows": rows.reshape(s_l * nt, -1),
                 "seg": seg.reshape(s_l * nt, et),
                 "lsrc": lsrc.reshape(s_l * nt, et),
-                "svids": svids.reshape(s_l * nt, -1),
                 "w": w.reshape(s_l * nt, et),
                 "emask": emask.reshape(s_l * nt, et),
                 "gsrc": gsrc.reshape(s_l * nt, et),
                 "gdst": gdst.reshape(s_l * nt, et),
             }
             # per-device partial combine happens inside csr_aggregate:
-            # every tile's row partials (and the flat variant's direct
-            # segment reduce) land in one (N, K) aggregate per device.
-            # The per-edge frontier filter (act) is trajectory-identical
-            # to the block path's block-granularity skipping for the
-            # idempotent monoids that drive frontiers
+            # every tile's row partials land in one (N, K) aggregate per
+            # device.  The per-edge frontier filter (act) is
+            # trajectory-identical to the block path's block-granularity
+            # skipping for the idempotent monoids that drive frontiers
             agg, cnt, live = kops.csr_aggregate(
                 state, aux, csr, program=program, num_vertices=n,
-                config=cfg, active=act)
+                active=act)
             with jax.named_scope("plug.gather"):
                 tiles_run = live.reshape(s_l, nt).sum(axis=1).astype(
                     jnp.int32)

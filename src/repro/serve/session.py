@@ -94,7 +94,7 @@ class GraphServeSession:
         self.daemon_name = daemon
         self.upper_name = upper
         self.kernel = kernel
-        self.csr_config = csr_config  # pinned CSR kernel config; None tunes
+        self.csr_config = csr_config  # CSR tile config; None → the default
         self.max_batch = int(max_batch)
         self.block_size = block_size
         self.monitor = monitor

@@ -5,9 +5,8 @@ matrix directly: if a refactor of bench_accel drops a cell, the ratio
 computation must KeyError loudly rather than silently shrink the
 summary.  This file pins both sides of that contract:
 
-* the recorded artifact carries EVERY kernel×model cell, the
-  pallas/reference ratios, and the autotune sweep tables that chose the
-  CSR configs (the full per-config table, not just the winner);
+* the recorded artifact carries EVERY kernel×model cell and the
+  pallas/reference ratios;
 * ``_summarize`` raises on a missing cell and mentions the pallas path.
 
 Timing VALUES are deliberately not pinned (the perf acceptance lives in
@@ -80,23 +79,6 @@ def test_baseline_ratios_consistent_with_cells(baseline, alg):
     for m in MODELS:
         want = mx["per_iter_s"][f"pallas/{m}"] / mx["per_iter_s"][f"reference/{m}"]
         assert mx["pallas_vs_reference"][m] == pytest.approx(want, rel=1e-9)
-
-
-def test_baseline_records_autotune_sweep_tables(baseline):
-    """Every pallas cell was produced by an autotuned CSR config; the
-    artifact must carry the full sweep table per signature so the choice
-    is auditable, with the chosen label the table's argmin."""
-    from repro.kernels.autotune import DEFAULT_SPACE
-
-    at = baseline["autotune"]
-    assert at["sweeps"] >= 1 and at["entries"]
-    labels = {c.label for c in DEFAULT_SPACE}
-    for entry in at["entries"]:
-        assert entry["monoid"] in {"sum", "min", "max", "or"}
-        assert set(entry["table"]) == labels
-        assert all(t > 0 for t in entry["table"].values())
-        assert entry["chosen"] in entry["table"]
-        assert entry["table"][entry["chosen"]] == min(entry["table"].values())
 
 
 def test_baseline_meta_and_fault_recovery_rows(baseline):

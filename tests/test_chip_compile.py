@@ -17,7 +17,6 @@ from jax.sharding import SingleDeviceSharding
 from repro.graph import generate
 from repro.graph.algorithms import pagerank, sssp_bf
 from repro.kernels import ops
-from repro.kernels.autotune import CSRConfig
 from repro.kernels.edge_block import csr_tile_pallas
 
 # Graph500 SCALE-22 tiles: 512 edges, row and src blocks as wide as the
@@ -113,14 +112,12 @@ def test_csr_tile_kernel_with_an_activity_row_compiles_for_v5e(
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("gather", ["take", "onehot"])
-def test_csr_aggregate_compiles_for_v5e(one_chip, monkeypatch, gather):
+@pytest.mark.parametrize("monoid", ["sum", "min"])
+def test_csr_aggregate_compiles_for_v5e(one_chip, monkeypatch, monoid):
     """The daemon's whole tile aggregate — K-major gathers, the kernel,
     the cross-tile combine — as the fused step traces it on a TPU."""
     monkeypatch.setattr(ops, "_default_interpret", lambda: False)
-    prog = _program("min")
-    cfg = CSRConfig(edge_tile=ET, lowering="pallas", merge="onehot",
-                    gather=gather)
+    prog = _program(monoid)
 
     def s(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -133,20 +130,18 @@ def test_csr_aggregate_compiles_for_v5e(one_chip, monkeypatch, gather):
             s((N, 1), jnp.float32), csr)
     text = _compiled_text(
         lambda st, ax, c: ops.csr_aggregate(st, ax, c, program=prog,
-                                            num_vertices=N, config=cfg),
+                                            num_vertices=N),
         args)
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("gather", ["take", "onehot"])
+@pytest.mark.parametrize("monoid", ["sum", "min"])
 def test_csr_aggregate_with_a_frontier_compiles_for_v5e(one_chip,
-                                                        monkeypatch, gather):
+                                                        monkeypatch, monoid):
     """The frontier step's tile aggregate: the activity gathered with the
     src state in one pass, the kernel's tile counts read back."""
     monkeypatch.setattr(ops, "_default_interpret", lambda: False)
-    prog = _program("min")
-    cfg = CSRConfig(edge_tile=ET, lowering="pallas", merge="onehot",
-                    gather=gather)
+    prog = _program(monoid)
 
     def s(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -159,8 +154,7 @@ def test_csr_aggregate_with_a_frontier_compiles_for_v5e(one_chip,
             s((N, 1), jnp.float32), csr, s((N,), jnp.bool_))
     text = _compiled_text(
         lambda st, ax, c, act: ops.csr_aggregate(
-            st, ax, c, program=prog, num_vertices=N, config=cfg,
-            active=act),
+            st, ax, c, program=prog, num_vertices=N, active=act),
         args)
     assert "tpu_custom_call" in text
 

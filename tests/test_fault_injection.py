@@ -98,25 +98,23 @@ def test_kill_equivalence_matrix(alg, model):
 def test_kill_mid_run_with_pallas_kernel(model):
     """The fused CSR tile daemon (kernel="pallas") survives a mid-run
     kill exactly like the reference kernel: bind_shards re-compacts and
-    re-stacks the CSR tiles for the survivor mesh (reusing the already-
-    autotuned config — no re-sweep during migration) and the fixed
-    point stays bit-identical to the uninterrupted reference."""
-    from repro.kernels.autotune import CACHE
-
+    re-stacks the CSR tiles for the survivor mesh (with the config the
+    daemon was bound with) and the fixed point stays bit-identical to the
+    uninterrupted reference."""
     g = _graph("sssp_bf")
     prog = sssp_bf(g)
     mw = _elastic(prog, g, model=model, kills=[(KILL_IT, 2)],
                   kernel="pallas")
     assert mw._fused_kind == ("async" if model == "async" else "bsp")
-    sweeps_before_run = CACHE.sweeps
+    cfg_before_run = mw.daemon._csr_config
     res = mw.run(max_iterations=REF_MAX_IT)
     assert res.converged
     migs = _migrations(res)
     assert len(migs) == 1
     assert migs[0]["killed"] == [2]
     assert 2 not in migs[0]["device_ids"]
-    # migration re-stacked tiles with the pinned config: no extra sweep
-    assert CACHE.sweeps == sweeps_before_run
+    # migration re-stacked tiles with the bound config
+    assert mw.daemon._csr_config is cfg_before_run
     assert "csr" in mw.daemon.stacked  # still on the CSR fused path
     np.testing.assert_array_equal(_converged_reference("sssp_bf"),
                                   res.state)

@@ -117,9 +117,9 @@ def test_ssd_final_state_matches_sequential():
 
 
 # --------------------------------------------------------------------------
-# CSR tile kernel battery: compaction invariants, differential tests over
-# the full autotune space (pallas ≡ XLA twin ≡ flat ≡ naive numpy oracle),
-# adversarial graphs, frontier filtering, autotune cache
+# CSR tile kernel battery: compaction invariants, differential tests of
+# the one tile path at several tile sizes against a naive numpy oracle,
+# adversarial graphs, frontier filtering, the config's one path
 # --------------------------------------------------------------------------
 try:
     from hypothesis import given, settings, strategies as st
@@ -129,21 +129,17 @@ except ImportError:
 from repro.core.template import MIN, MAX, OR, SUM, Monoid, VertexProgram
 from repro.graph.compaction import (build_csr_tiles, pad_tileset,
                                     tiles_from_blockset)
-from repro.kernels.autotune import (AutotuneCache, CSRConfig, DEFAULT_SPACE,
-                                    autotune_csr)
+from repro.kernels.autotune import CSRConfig
 
 N_V = 24  # deliberately not a multiple of 8: exercises RT/ST rounding
 
-# small tiles force multi-tile layouts + hub splitting on tiny graphs;
-# one config per (lowering, merge, gather) family of the tuning space
+# the one tile path at (edge_tile, hub_threshold) points: small tiles and
+# a low hub threshold force multi-tile layouts and hub splitting on tiny
+# graphs; the default tile holds a whole test graph
 TEST_SPACE = (
-    CSRConfig(edge_tile=32, merge="flat"),
-    CSRConfig(edge_tile=32, merge="sorted", gather="take"),
-    CSRConfig(edge_tile=32, merge="onehot", gather="onehot"),
-    CSRConfig(edge_tile=32, lowering="pallas", merge="onehot",
-              gather="take"),
-    CSRConfig(edge_tile=32, lowering="pallas", merge="onehot",
-              gather="onehot"),
+    CSRConfig(edge_tile=8, hub_threshold=3),
+    CSRConfig(edge_tile=32),
+    CSRConfig(edge_tile=512),
 )
 
 _GEN = {
@@ -203,16 +199,16 @@ def _oracle(prog, state, aux, src, dst, w, active=None):
 
 
 def _run_cfg(cfg, prog, state, aux, src, dst, w, active=None):
-    """One tuning-space point, run eagerly (tiny adversarial shapes —
-    avoids a jit recompile per drawn example)."""
+    """One tile-size point, run eagerly (tiny adversarial shapes —
+    avoids a jit recompile per drawn example).  A frontier rides in the
+    src gather, as in the sharded daemon's step."""
     ts = build_csr_tiles(src, dst, w, N_V, edge_tile=cfg.edge_tile,
                          hub_threshold=cfg.hub_threshold)
     csr = {k: jnp.asarray(v) for k, v in ts.arrays().items()}
-    if active is not None:
-        csr["emask"] = csr["emask"] & jnp.asarray(active)[csr["gsrc"]]
     agg, cnt, _ = ops.csr_aggregate(
         jnp.asarray(state), jnp.asarray(aux), csr, program=prog,
-        num_vertices=N_V, config=cfg)
+        num_vertices=N_V,
+        active=None if active is None else jnp.asarray(active))
     return np.asarray(agg), np.asarray(cnt)
 
 
@@ -408,13 +404,15 @@ def test_csr_tiles_match_per_tile_loop(seed, et, hub):
 
 def test_pad_tileset_preserves_aggregate_bit_for_bit():
     """Padding a tile set to a bigger (nt, RT, ST) envelope (the sharded
-    daemon's rectangular stacking) must not change any variant's output."""
+    daemon's rectangular stacking) must not change the output at any
+    tile size."""
     g = generate.rmat(N_V, 160, seed=11)
     prog = _program(MIN)
     state, aux = _state_for(MIN)
     for cfg in TEST_SPACE:
         ts = build_csr_tiles(g.src, g.dst, g.weights, N_V,
-                             edge_tile=cfg.edge_tile)
+                             edge_tile=cfg.edge_tile,
+                             hub_threshold=cfg.hub_threshold)
         padded = pad_tileset(ts, num_tiles=ts.num_tiles + 3,
                              row_tile=ts.row_tile + 8,
                              src_tile=ts.src_tile + 16)
@@ -423,7 +421,7 @@ def test_pad_tileset_preserves_aggregate_bit_for_bit():
             csr = {k: jnp.asarray(v) for k, v in t.arrays().items()}
             agg, cnt, _ = ops.csr_aggregate(
                 jnp.asarray(state), jnp.asarray(aux), csr, program=prog,
-                num_vertices=N_V, config=cfg)
+                num_vertices=N_V)
             outs.append((np.asarray(agg), np.asarray(cnt)))
         np.testing.assert_array_equal(outs[0][0], outs[1][0],
                                       err_msg=cfg.label)
@@ -439,7 +437,7 @@ def test_pad_tileset_rejects_shrinking():
                     src_tile=ts.src_tile)
 
 
-# -- differential property tests over the tuning space ----------------------
+# -- differential property tests over the tile sizes ------------------------
 @settings(max_examples=12, deadline=None)
 @given(_pairs)
 def test_csr_variants_match_oracle_min(pairs):
@@ -466,7 +464,7 @@ def test_csr_variants_match_oracle_sum(pairs):
 
 def test_csr_sum_bit_exact_on_integer_messages():
     """Integer-valued states/weights make sum exact in f32 at this scale:
-    every variant must then agree with the oracle bit for bit, not just
+    every tile size must then agree with the oracle bit for bit, not just
     to tolerance — merge order can no longer hide a wrong edge."""
     rng = np.random.default_rng(4)
     src = rng.integers(0, N_V, 300).astype(np.int32)
@@ -498,7 +496,7 @@ _ADVERSARIAL = {
 @pytest.mark.parametrize("monoid", [MIN, MAX, SUM, OR],
                          ids=lambda m: m.name)
 def test_csr_adversarial_fixtures(case, monoid):
-    """Named adversarial shapes × every monoid × every variant."""
+    """Named adversarial shapes × every monoid × every tile size."""
     _assert_variants_match(monoid, *_edges_from_pairs(_ADVERSARIAL[case]))
 
 
@@ -520,8 +518,9 @@ def test_csr_all_inactive_frontier_yields_identity():
 @settings(max_examples=10, deadline=None)
 @given(_pairs, st.lists(st.integers(0, N_V - 1), min_size=0, max_size=10))
 def test_csr_frontier_matches_filtered_oracle(pairs, active_ids):
-    """Per-edge frontier filtering (emask & active[gsrc]) equals the
-    oracle run on the filtered edge list — bit-identically for min."""
+    """Per-edge frontier filtering (the activity folded into the src
+    gather) equals the oracle run on the filtered edge list —
+    bit-identically for min."""
     src, dst, w = _edges_from_pairs(pairs)
     active = np.zeros(N_V, bool)
     active[np.asarray(active_ids, np.int64)] = True
@@ -550,8 +549,8 @@ def test_csr_daemon_run_blocks_matches_reference_daemon():
 
 
 def test_csr_unknown_monoid_raises_in_every_variant():
-    """An unregistered monoid must raise (with its name) from every merge
-    family, never silently merge with the wrong operator."""
+    """An unregistered monoid must raise (with its name) at every tile
+    size, never silently merge with the wrong operator."""
     weird = Monoid("product", 1.0, jnp.multiply, idempotent=False)
     prog = VertexProgram(
         name="weird", state_width=2, aux_width=1, monoid=weird,
@@ -564,46 +563,16 @@ def test_csr_unknown_monoid_raises_in_every_variant():
             _run_cfg(cfg, prog, state, aux, g.src, g.dst, g.weights)
 
 
-# -- autotune ----------------------------------------------------------------
-def test_autotune_cache_hit_skips_resweep():
-    """Identically-shaped second bind is a pure cache lookup: the sweep
-    counter must not move (the regression the issue pins — re-sweeping
-    on every bind would swamp short runs)."""
-    g = generate.rmat(N_V, 150, seed=8)
-    prog = _program(MIN)
-    cache = AutotuneCache()
-    cfg1 = autotune_csr(g.src, g.dst, g.weights, N_V, prog, cache=cache,
-                        repeats=1)
-    assert (cache.sweeps, cache.hits) == (1, 0)
-    cfg2 = autotune_csr(g.src, g.dst, g.weights, N_V, prog, cache=cache,
-                        repeats=1)
-    assert (cache.sweeps, cache.hits) == (1, 1)  # no re-sweep
-    assert cfg1 is cfg2
-    # a different shape is a different signature: sweeps again
-    g2 = generate.rmat(N_V, 90, seed=8)
-    autotune_csr(g2.src, g2.dst, g2.weights, N_V, prog, cache=cache,
-                 repeats=1)
-    assert cache.sweeps == 2
-
-
-def test_autotune_report_records_full_sweep_table():
-    """The report (exported into BENCH_plug.json) carries the chosen
-    config and a timing for EVERY point of the space — the sweep is
-    auditable, not just its winner."""
-    g = generate.rmat(N_V, 150, seed=8)
-    prog = _program(MIN)
-    cache = AutotuneCache()
-    chosen = autotune_csr(g.src, g.dst, g.weights, N_V, prog, cache=cache,
-                          repeats=1)
-    rep = cache.report()
-    assert rep["sweeps"] == 1
-    (entry,) = rep["entries"]
-    assert entry["monoid"] == "min"
-    assert entry["chosen"] == chosen.label
-    labels = {c.label for c in DEFAULT_SPACE}
-    assert set(entry["table"]) == labels
-    assert all(t > 0 for t in entry["table"].values())
-    assert entry["table"][chosen.label] == min(entry["table"].values())
+# -- the config's one path --------------------------------------------------
+@pytest.mark.parametrize("field,value", [("lowering", "xla"),
+                                         ("merge", "flat"),
+                                         ("merge", "sorted"),
+                                         ("gather", "onehot")])
+def test_csr_config_rejects_any_other_path(field, value):
+    """The config names the one tile path; a retired variant raises."""
+    with pytest.raises(ValueError, match=field):
+        CSRConfig(**{field: value})
+    assert CSRConfig().label == "pallas/onehot/take/et512"
 
 
 def test_or_monoid_contract():
